@@ -632,6 +632,8 @@ def main() -> None:
                          "(runtime range telemetry on) and write "
                          "DIR/<name>.trace.json + DIR/<name>.jsonl")
     args = ap.parse_args()
+    from repro import compile_cache
+    compile_cache.enable()
     _register()
     names = [n for n in args.only.split(",") if n] or list(BENCHES)
 

@@ -23,12 +23,11 @@ Two entry points live here:
   * `fused_pipeline` — the multi-stage generalization the plan-driven
     lowering (`repro.lowering.pallas_backend`) compiles into: one grid
     walks a band schedule over the whole stage DAG, every intermediate
-    stage's rows stay in VMEM, and taps are resolved by clamped gathers
-    that handle non-unit stride, upsampling, multi-input stages, and
-    edge-replicate padding without materializing anything to HBM.  The
-    kernel body here owns the *geometry* (band loads, tap index algebra);
-    the caller supplies each stage's datapath as a closure
-    ``fn(tap, rows_abs) -> tile`` so this module stays IR-agnostic.
+    stage's rows stay in VMEM, and each input band arrives by a
+    double-buffered DMA.  The kernel body here owns the *geometry* (band
+    loads, tap offsets, rate changes, edge replication); the caller
+    supplies each stage's datapath as a closure
+    ``fn(tap, rows, cols) -> tile`` so this module stays IR-agnostic.
 
 Stage descriptors for `fused_pipeline` are plain dicts:
 
@@ -37,25 +36,33 @@ Stage descriptors for `fused_pipeline` are plain dicts:
     step      output rows per grid tile
     lo, L     row-span start (relative to i*step) and length
     H, W      full stage height/width
+    M, Wb     column blocks and lanes per block (see `eval_band`)
     dtype     tile/output dtype
     in_slot   (inputs) operand index of the pallas_call
     stride, upsample, fn   (compute) vertical/horizontal rates + datapath
+    tapped    whether a compute stage of the program reads this stage
     out_slot  optional output index
 
-Tap resolution implements the executor's exact sampling semantics: output
-row `y` of a stage reads its input at row `floor((y*sy + dy) / uy)`
-(upsample-expand, shift, decimate), clamped to the valid grid — which is
-provably identical to edge-padding the expanded array like
-`dsl.exec._pad_inputs` does.
+Taps implement the executor's exact sampling semantics: output row `y`
+of a stage reads its input at row `clip(floor((y*sy + dy) / uy))`
+(upsample-expand, shift, decimate, edge-replicate) — identical to
+edge-padding the expanded array like `dsl.exec._pad_inputs` does.  Every
+tap is a static slice, reshape or lane shift: band rows are *virtual*
+rows (the lattice-aligned schedule makes each tap's band offset
+independent of the band index), and border bands replace the rows past
+the image edge with the edge row (`_edge_fix`).
 """
 from __future__ import annotations
 
 import functools
+from math import gcd
 from typing import Callable, Dict, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 Tap = Tuple[int, int, int]   # (dy, dx, w_q)
 Halo = Union[int, Tuple[int, int]]
@@ -116,58 +123,210 @@ def fixedpoint_stencil(x_q: jax.Array, taps: Sequence[Tap], halo: Halo,
 # fused multi-stage band kernel
 # ---------------------------------------------------------------------------
 
-def eval_band(program: Sequence[Dict], i, load_band) -> Dict[str, jax.Array]:
+# Scoped VMEM the fused kernel may claim (the compiler's default scoped
+# limit is far below both a v5e core's VMEM and what a 4K band program of
+# a deep pipeline keeps live).  `tests/test_tpu_compile.py` compiles the 4K
+# dus_ext band program under it.
+VMEM_LIMIT_BYTES = 96 * 1024 * 1024
+# rows of the TPU's (8, 128) vector tile: output blocks and band DMA
+# windows start and span multiples of it (`repro.lowering.schedule`)
+ROW_ALIGN = 8
+LANES = 128
+
+
+def _wide(x):
+    """The 32-bit view taps shuffle: Mosaic decimates, repeats and
+    interleaves rows of 32-bit vectors only (packed 8/16-bit rows share a
+    sublane), so narrow containers widen once per band before their taps
+    read them — value-preserving, every container fits int32."""
+    dt = jnp.dtype(x.dtype)
+    if dt.itemsize >= 4:
+        return x
+    return x.astype(jnp.float32 if jnp.issubdtype(dt, jnp.floating)
+                    else jnp.int32)
+
+
+def _lowest(dt):
+    dt = jnp.dtype(dt)
+    if jnp.issubdtype(dt, jnp.floating):
+        return -jnp.inf
+    return jnp.iinfo(dt).min
+
+
+def _bcast_rows(x, row: int, n: int):
+    return jnp.broadcast_to(x[row:row + 1], (n,) + x.shape[1:])
+
+
+def _affine_rows(x, n: int, s: int, u: int, c: int):
+    """Rows ``floor((r*s + c) / u)`` of `x` for r in [0, n) — static.
+
+    Decimation is a reshape, upsampling an interleave of shifted slices;
+    rows past the end of `x` (only ever read by rows the caller drops or
+    by interleave padding) replicate the last row."""
+    g = gcd(s, u)
+    s, u, c = s // g, u // g, c // g          # floor(c/g): exact, see below
+    Lx = x.shape[0]
+    if u == 1:
+        if c >= Lx:
+            return _bcast_rows(x, Lx - 1, n)
+        y = x[c:c + n * s]
+        if y.shape[0] < n * s:
+            y = jnp.concatenate(
+                [y, _bcast_rows(x, Lx - 1, n * s - y.shape[0])], axis=0)
+        if s == 1:
+            return y
+        return y.reshape((n, s) + x.shape[1:])[:, 0]
+    # coprime s, u > 1: output row r = m*u + t reads m*s + floor((t*s+c)/u)
+    m = -(-n // u)
+    phases = [_affine_rows(x, m, s, 1, (t * s + c) // u) for t in range(u)]
+    return jnp.stack(phases, axis=1).reshape((m * u,) + x.shape[1:])[:n]
+
+
+def take_rows(x, n: int, s: int, u: int, c: int):
+    """Rows ``clip(floor((r*s + c) / u), 0, len(x) - 1)`` for r in [0, n).
+
+    The one row geometry of a tap: `s`/`u` are the consumer's vertical
+    stride/upsample and `c` its band-relative offset, all static, so the
+    result is slices, a reshape or an interleave — never a gather.  The
+    clip replicates the first/last row of `x`: a no-op under a banded
+    schedule (the span pass keeps every tap inside the parent band) and
+    the oracle's edge-replicate clamp under a single-tile schedule.
+    (``floor((r*s + c)/u) == floor((r*s/g + floor(c/g)) / (u/g))`` for
+    ``g = gcd(s, u)``, which `_affine_rows` relies on.)"""
+    Lx = x.shape[0]
+    raw = (np.arange(n) * s + c) // u         # non-decreasing
+    n_lo = int((raw < 0).sum())
+    n_hi = int((raw >= Lx).sum())
+    parts = []
+    if n_lo:
+        parts.append(_bcast_rows(x, 0, n_lo))
+    if n - n_lo - n_hi:
+        parts.append(_affine_rows(x, n - n_lo - n_hi, s, u, n_lo * s + c))
+    if n_hi:
+        parts.append(_bcast_rows(x, Lx - 1, n_hi))
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
+
+
+def _gather_lanes(block_of, blk: np.ndarray, lane: np.ndarray):
+    """Static lane gather over column blocks: output lane k is lane
+    ``lane[k]`` of block ``blk[k]``.  Emitted as maximal runs of
+    contiguous lanes (slices) and of one repeated lane (broadcasts of an
+    edge column)."""
+    parts = []
+    q, n = 0, len(blk)
+    while q < n:
+        b, l = int(blk[q]), int(lane[q])
+        x = block_of(b)
+        k = q + 1
+        if k < n and blk[k] == b and lane[k] == l + 1:
+            while k < n and blk[k] == b and lane[k] == lane[k - 1] + 1:
+                k += 1
+            parts.append(x[:, l:l + k - q])
+        else:
+            while k < n and blk[k] == b and lane[k] == l:
+                k += 1
+            parts.append(jnp.broadcast_to(x[:, l:l + 1], (x.shape[0], k - q)))
+        q = k
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+
+
+def _border_possible(d: Dict, nbands: int) -> bool:
+    return d["lo"] < 0 or (nbands - 1) * d["step"] + d["lo"] + d["L"] > d["H"]
+
+
+def _edge_fix(blocks, start, H: int, L: int):
+    """Edge-replicate a band tile at the image border.
+
+    A band tile holds rows ``[start, start + L)`` of its stage computed
+    on *virtual* rows; rows above 0 / below ``H - 1`` must hold the
+    stage's row 0 / row ``H - 1`` (the oracle pads every stage's input
+    that way).  Only border bands take the fix (`lax.cond`); the schedule
+    guarantees every band overlaps its stage, so both edge rows it needs
+    are inside the tile."""
+    rid = start + jax.lax.broadcasted_iota(jnp.int32, (L, 1), 0)
+
+    def fix(bs):
+        out = []
+        for b in bs:
+            low = _lowest(b.dtype)
+            top = jnp.max(jnp.where(rid == 0, b, low), axis=0, keepdims=True)
+            bot = jnp.max(jnp.where(rid == H - 1, b, low), axis=0,
+                          keepdims=True)
+            out.append(jnp.where(rid < 0, top, jnp.where(rid >= H, bot, b)))
+        return out
+
+    return jax.lax.cond((start < 0) | (start + L > H), fix,
+                        lambda bs: list(bs), list(blocks))
+
+
+def eval_band(program: Sequence[Dict], i, load_band,
+              nbands: int) -> Dict[str, list]:
     """Evaluate one band step `i` of a fused stage program.
 
-    This is the ONE definition of the band geometry — the tap index
-    algebra and edge-replicate clamps — shared by the pallas kernel
-    (`_fused_kernel`, where `load_band` slices an HBM ref) and the
-    `shard_map` band-sharded executor (`repro.lowering.sharded`, where
-    `load_band` is a `dynamic_slice` on a device-local array).  Sharing
-    it is what makes the sharded program bit-identical to the fused
-    kernel by construction.
+    This is the ONE definition of the band geometry — tap offsets, rate
+    changes and edge-replicate clamps — shared by the pallas kernel
+    (`_fused_kernel`, where `load_band` reads the band its DMA fetched)
+    and the `shard_map` band-sharded executor (`repro.lowering.sharded`,
+    where `load_band` is a `dynamic_slice` on a device-local array).
+    Sharing it is what makes the sharded program bit-identical to the
+    fused kernel by construction.
 
-    `load_band(d, start)` returns the contiguous `(d["L"], d["W"])` band
-    of input descriptor `d` beginning at (already-clamped) row `start`.
-    `i` may be a traced index (pallas `program_id` or a shard's
-    `axis_index`-derived step).  Returns the full tile dict.
+    Every stage is held in ``M`` column blocks (``d["M"]``): block ``p``
+    lane ``q`` is column ``q*M + p``.  Column decimation and upsampling
+    then only ever pick whole blocks and shift lanes, and row rate
+    changes are static reshapes (`take_rows`), so every tap is a static
+    slice — the form Mosaic compiles.
+
+    `load_band(d, start)` returns input descriptor `d`'s band of virtual
+    rows ``[start, start + d["L"])`` as ``(M, L, Wb)``; rows outside the
+    image may hold anything.  `i` may be traced (pallas `program_id`, a
+    shard's band index); `nbands` is the number of bands walked.
+    Returns ``{stage: [block (L, Wb), ...]}`` in stored dtypes.
     """
     by_name = {d["name"]: d for d in program}
-    tiles: Dict[str, jax.Array] = {}
+    tiles: Dict[str, list] = {}
+    wide: Dict[str, list] = {}
     for d in program:
         start = i * d["step"] + d["lo"]
-        L, H = d["L"], d["H"]
+        L, M = d["L"], d["M"]
         if d["kind"] == "input":
-            # contiguous band load (the line-buffer copy), then reorder
-            # with clamped indices for the edge-replicate rows
-            b = jnp.clip(start, 0, H - L)
-            band = load_band(d, b)
-            idx = jnp.clip(start + jnp.arange(L), 0, H - 1) - b
-            tiles[d["name"]] = jnp.take(band, idx, axis=0)
+            band = load_band(d, start)
+            blocks = [band[p] for p in range(M)]
         else:
-            rows_abs = jnp.clip(start + jnp.arange(L), 0, H - 1)
             sy, sx = d["stride"]
             uy, ux = d["upsample"]
-            W = d["W"]
+            rows = start + jax.lax.broadcasted_iota(jnp.int32, (L, 1), 0)
+            lanes = np.arange(d["Wb"])
+            row_views: Dict[tuple, jax.Array] = {}
 
-            def tap(pname, dy, dx, *, rows_abs=rows_abs, sy=sy, sx=sx,
-                    uy=uy, ux=ux, W=W):
+            def tap(pname, dy, dx, *, p, d=d, sy=sy, sx=sx, uy=uy, ux=ux,
+                    lanes=lanes, row_views=row_views):
                 pd = by_name[pname]
-                p_start = i * pd["step"] + pd["lo"]
-                # band-relative clamp: a no-op under a banded schedule
-                # (the span pass keeps src inside the parent band) but
-                # load-bearing for single-tile schedules, where the full
-                # parent column is resident and clamping to [0, L-1] IS
-                # the oracle's absolute edge-replicate clamp
-                src = jnp.clip(
-                    jnp.floor_divide(rows_abs * sy + dy, uy) - p_start,
-                    0, pd["L"] - 1)
-                t = jnp.take(tiles[pname], src, axis=0)
-                cols = jnp.clip(jnp.floor_divide(jnp.arange(W) * sx + dx, ux),
-                                0, pd["W"] - 1)
-                return jnp.take(t, cols, axis=1)
+                c = d["lo"] * sy + dy - pd["lo"] * uy
 
-            tiles[d["name"]] = d["fn"](tap, rows_abs)
+                def block_of(b):
+                    key = (pname, b, c)
+                    if key not in row_views:
+                        row_views[key] = take_rows(wide[pname][b], d["L"],
+                                                   sy, uy, c)
+                    return row_views[key]
+
+                col = np.clip(((lanes * d["M"] + p) * sx + dx) // ux,
+                              0, pd["W"] - 1)
+                return _gather_lanes(block_of, col % pd["M"], col // pd["M"])
+
+            blocks = []
+            for p in range(M):
+                cols = (jax.lax.broadcasted_iota(jnp.int32, (1, d["Wb"]), 1)
+                        * M + p)
+                blocks.append(d["fn"](functools.partial(tap, p=p), rows,
+                                      cols))
+        tiles[d["name"]] = blocks
+        if d.get("tapped"):
+            w = [_wide(b) for b in blocks]
+            if _border_possible(d, nbands):
+                w = _edge_fix(w, start, d["H"], L)
+            wide[d["name"]] = w
     return tiles
 
 
@@ -176,54 +335,87 @@ def band_output(d: Dict, tile: jax.Array) -> jax.Array:
     return tile[-d["lo"]: -d["lo"] + d["step"]]
 
 
+def dma_window(d: Dict) -> Tuple[int, int, int]:
+    """``(first, offset, length)`` of an input's band DMA window.
+
+    The TPU copies whole (8, 128) tiles of an HBM array, so the window a
+    band's copy fetches starts on a multiple of `ROW_ALIGN` rows of the
+    row-padded input: band ``i`` copies rows ``[first + i*step, first +
+    i*step + length)`` and its virtual rows ``[i*step + lo, ... + L)``
+    sit at the static `offset` inside (the schedule keeps an input's
+    `step` a multiple of `ROW_ALIGN` whenever there is more than one
+    band)."""
+    base = d["lo"] + max(0, -d["lo"])        # first band row, padded
+    offset = base % ROW_ALIGN
+    length = -(-(offset + d["L"]) // ROW_ALIGN) * ROW_ALIGN
+    return base - offset, offset, length
+
+
+def input_pads(d: Dict, nbands: int) -> Tuple[int, int]:
+    """Rows an input is padded with above/below so that every band
+    window of a `nbands` walk loads in bounds (the padding's values are
+    never used: `eval_band` edge-replicates virtual rows)."""
+    top = max(0, -d["lo"])
+    first, _, length = dma_window(d)
+    bot = max(0, (nbands - 1) * d["step"] + first + length - top - d["H"])
+    return top, bot
+
+
+def _input_lanes(d: Dict) -> int:
+    """Lanes of an input's block arrays: `Wb` rounded up to whole
+    128-lane tiles, so band DMAs copy whole tiles (extra lanes are never
+    read)."""
+    return -(-d["Wb"] // LANES) * LANES
+
+
+def to_blocks(x, d: Dict, nbands: int):
+    """``(..., H, W)`` image -> ``(..., M, top + H + bot, lanes)`` column
+    blocks (block ``p`` lane ``q`` = column ``q*M + p``), row-padded per
+    `input_pads` and lane-padded per `_input_lanes`."""
+    M, Wb = d["M"], _input_lanes(d)
+    top, bot = input_pads(d, nbands)
+    cfg = [(0, 0)] * (x.ndim - 2) + [(top, bot), (0, M * Wb - x.shape[-1])]
+    x = jnp.pad(x, cfg)
+    x = x.reshape(x.shape[:-1] + (Wb, M))
+    return jnp.moveaxis(x, -1, -3)
+
+
+def from_blocks(y, d: Dict):
+    """Inverse of `to_blocks` for an output stage: ``(..., M, H', Wb)``
+    -> ``(..., H, W)`` (drops ragged-band rows and block padding)."""
+    y = jnp.moveaxis(y, -3, -1)
+    y = y.reshape(y.shape[:-2] + (y.shape[-2] * y.shape[-1],))
+    return y[..., :d["H"], :d["W"]]
+
+
 def _write_outputs(program: Sequence[Dict], tiles: Dict, out_refs,
                    batched: bool) -> None:
     for d in program:
         slot = d.get("out_slot")
-        if slot is not None:
-            rows = band_output(d, tiles[d["name"]])
+        if slot is None:
+            continue
+        for p, tile in enumerate(tiles[d["name"]]):
+            rows = band_output(d, tile)
             if batched:
-                out_refs[slot][0] = rows      # block carries a unit batch dim
+                out_refs[slot][0, p] = rows   # block carries a unit batch dim
             else:
-                out_refs[slot][...] = rows
+                out_refs[slot][p] = rows
 
 
-def _fused_kernel(*refs, program: Sequence[Dict], n_in: int, batched: bool):
-    in_refs, out_refs = refs[:n_in], refs[n_in:]
-    if batched:
-        # batch is the outer grid axis: one image's band walk per inner
-        # step, intermediates still VMEM-only per (image, band)
-        bi, i = pl.program_id(0), pl.program_id(1)
-
-        def load_band(d, start):
-            return in_refs[d["in_slot"]][bi, pl.ds(start, d["L"]), :]
-    else:
-        i = pl.program_id(0)
-
-        def load_band(d, start):
-            return in_refs[d["in_slot"]][pl.ds(start, d["L"]), :]
-
-    tiles = eval_band(program, i, load_band)
-    _write_outputs(program, tiles, out_refs, batched)
-
-
-def _fused_kernel_prefetch(*refs, program: Sequence[Dict], n_in: int,
-                           n_out: int, batched: bool, nbands: int):
-    """The double-buffered variant of `_fused_kernel`.
+def _fused_kernel(*refs, program: Sequence[Dict], n_in: int, n_out: int,
+                  batched: bool, nbands: int):
+    """One band step: double-buffered band DMA, then `eval_band`.
 
     Each HBM input gets a two-slot VMEM scratch: band `i` computes out
     of slot ``i % 2`` while the async copy of band ``i + 1`` fills the
     other slot, overlapping the HBM->VMEM line-buffer fill with compute
     (grid steps run sequentially per core, so scratch persists across
-    them).  Band start rows are data-independent — the same clamped
-    ``i*step + lo`` formula `eval_band` uses — so the prefetched band is
-    exactly the band the direct-slice kernel would load; the datapath is
-    untouched and exactness is unaffected.  Prefetch never crosses the
-    image boundary of the outer batch axis: each image's first band is
-    fetched under the ``i == 0`` warm-up (one bubble per image).
+    them).  Inputs arrive row-padded (`input_pads`), so every band's
+    copy fetches an aligned window in bounds (`dma_window`).  Prefetch never
+    crosses the image boundary of the outer batch axis: each image's
+    first band is fetched under the ``i == 0`` warm-up (one bubble per
+    image).
     """
-    from jax.experimental.pallas import tpu as pltpu
-
     in_refs = refs[:n_in]
     out_refs = refs[n_in:n_in + n_out]
     scratch = refs[n_in + n_out:]          # (vmem, sem) pair per input
@@ -237,15 +429,17 @@ def _fused_kernel_prefetch(*refs, program: Sequence[Dict], n_in: int,
     def dma(d, slot, j):
         vmem = scratch[2 * d["in_slot"]]
         sem = scratch[2 * d["in_slot"] + 1]
-        b = jnp.clip(j * d["step"] + d["lo"], 0, d["H"] - d["L"])
+        first, _, length = dma_window(d)
+        b = pl.multiple_of(j * d["step"] + first, ROW_ALIGN) \
+            if nbands > 1 else first
         src = in_refs[d["in_slot"]]
-        src = src.at[bi, pl.ds(b, d["L"]), :] if batched \
-            else src.at[pl.ds(b, d["L"]), :]
+        src = src.at[bi, :, pl.ds(b, length), :] if batched \
+            else src.at[:, pl.ds(b, length), :]
         return pltpu.make_async_copy(src, vmem.at[slot], sem.at[slot])
 
     for d in inputs:
         @pl.when(i == 0)                   # warm-up: fetch this image's
-        def _(d=d):                        # first band synchronously
+        def _(d=d):                        # first band
             dma(d, cur, i).start()
 
         @pl.when(i + 1 < nbands)
@@ -255,81 +449,70 @@ def _fused_kernel_prefetch(*refs, program: Sequence[Dict], n_in: int,
         dma(d, cur, i).wait()
 
     def load_band(d, start):
-        # `start` is the same clamped row the in-flight DMA used
-        return scratch[2 * d["in_slot"]][cur]
+        # `start` is the virtual row the DMA'd window holds at `offset`
+        _, offset, _ = dma_window(d)
+        return scratch[2 * d["in_slot"]][cur][:, offset:offset + d["L"],
+                                              :d["Wb"]]
 
-    tiles = eval_band(program, i, load_band)
+    tiles = eval_band(program, i, load_band, nbands)
     _write_outputs(program, tiles, out_refs, batched)
 
 
 def fused_pipeline(program: Sequence[Dict], grid: int,
                    interpret: bool = True,
-                   batch: int | None = None,
-                   prefetch: bool | None = None) -> Callable:
+                   batch: int | None = None) -> Callable:
     """Compile a band-scheduled stage program into one pallas_call.
 
-    Returns ``f(*input_arrays) -> tuple(output_arrays)``; see the module
-    docstring for the descriptor contract.  With `batch` the inputs and
-    outputs carry a leading batch dimension and the grid gains an outer
-    batch axis — `grid=(batch, bands)` — so every (image, band) pair is
-    one grid step of the same VMEM-resident band program.
-
-    `prefetch` selects the double-buffered band DMA
-    (`_fused_kernel_prefetch`): band i+1's HBM->VMEM copy overlaps band
-    i's compute through a two-slot scratch per input.  ``None`` (the
-    default) enables it exactly on native TPU runs — interpret mode
-    keeps the direct slice (the DMA emulation would only add overhead) —
-    but an explicit ``True`` works under interpret too, which is how the
-    tests pin the prefetch schedule bit-exact off-hardware.
+    Returns a jitted ``f(*input_arrays) -> tuple(output_arrays)`` over
+    plain ``(H, W)`` (or, with `batch`, ``(batch, H, W)``) arrays; see the
+    module docstring for the descriptor contract.  The wrapper converts
+    inputs to row-padded column blocks (`to_blocks`) and outputs back
+    (`from_blocks`) around the kernel.  With `batch` the grid gains an
+    outer batch axis — ``grid=(batch, bands)`` — so every (image, band)
+    pair is one grid step of the same VMEM-resident band program.  The
+    last band may be ragged: output blocks past a stage's height are
+    dropped on write.
     """
-    n_in = sum(1 for d in program if d["kind"] == "input")
+    ins = sorted((d for d in program if d["kind"] == "input"),
+                 key=lambda d: d["in_slot"])
     outs = sorted((d for d in program if d.get("out_slot") is not None),
                   key=lambda d: d["out_slot"])
-    if prefetch is None:
-        prefetch = not interpret and jax.default_backend() == "tpu"
     scratch_shapes = []
-    if prefetch and grid > 1:
-        try:
-            from jax.experimental.pallas import tpu as pltpu
-        except ImportError:            # no TPU lowering available: keep
-            prefetch = False           # the direct-slice kernel
-    if prefetch and grid > 1:
-        ins = sorted((d for d in program if d["kind"] == "input"),
-                     key=lambda d: d["in_slot"])
-        for d in ins:
-            scratch_shapes += [pltpu.VMEM((2, d["L"], d["W"]), d["dtype"]),
-                               pltpu.SemaphoreType.DMA((2,))]
-        kern = functools.partial(_fused_kernel_prefetch,
-                                 program=tuple(program), n_in=n_in,
-                                 n_out=len(outs),
-                                 batched=batch is not None, nbands=grid)
-    else:
-        kern = functools.partial(_fused_kernel, program=tuple(program),
-                                 n_in=n_in, batched=batch is not None)
+    for d in ins:
+        scratch_shapes += [pltpu.VMEM((2, d["M"], dma_window(d)[2],
+                                       _input_lanes(d)), d["dtype"]),
+                           pltpu.SemaphoreType.DMA((2,))]
+    kern = functools.partial(_fused_kernel, program=tuple(program),
+                             n_in=len(ins), n_out=len(outs),
+                             batched=batch is not None, nbands=grid)
     if batch is None:
-        out_specs = [pl.BlockSpec((d["step"], d["W"]), lambda i: (i, 0))
-                     for d in outs]
-        out_shape = [jax.ShapeDtypeStruct((d["H"], d["W"]), d["dtype"])
-                     for d in outs]
+        out_specs = [pl.BlockSpec((d["M"], d["step"], d["Wb"]),
+                                  lambda i: (0, i, 0)) for d in outs]
+        out_shape = [jax.ShapeDtypeStruct((d["M"], d["H"], d["Wb"]),
+                                          d["dtype"]) for d in outs]
         grid_dims: Tuple[int, ...] = (grid,)
     else:
-        out_specs = [pl.BlockSpec((1, d["step"], d["W"]),
-                                  lambda b, i: (b, i, 0)) for d in outs]
-        out_shape = [jax.ShapeDtypeStruct((batch, d["H"], d["W"]),
+        out_specs = [pl.BlockSpec((1, d["M"], d["step"], d["Wb"]),
+                                  lambda b, i: (b, 0, i, 0)) for d in outs]
+        out_shape = [jax.ShapeDtypeStruct((batch, d["M"], d["H"], d["Wb"]),
                                           d["dtype"]) for d in outs]
         grid_dims = (batch, grid)
     call = pl.pallas_call(
         kern,
         grid=grid_dims,
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * n_in,
+        in_specs=[pl.BlockSpec(memory_space=pltpu.HBM)] * len(ins),
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch_shapes,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
+        name="fused_band_island",
     )
 
     def run(*arrays):
-        out = call(*arrays)
-        return out if isinstance(out, (tuple, list)) else (out,)
+        out = call(*[to_blocks(a, d, grid) for a, d in zip(arrays, ins)])
+        out = out if isinstance(out, (tuple, list)) else (out,)
+        return tuple(from_blocks(y, d) for y, d in zip(out, outs))
 
-    return run
+    return jax.jit(run)

@@ -54,20 +54,19 @@ def rhe_shift(p, t: int):
     return base + inc.astype(p.dtype)
 
 
-def residue_bounds(phase: PhaseSnap, t: FixedPointType, rows_abs, W: int):
+def residue_bounds(phase: PhaseSnap, t: FixedPointType, rows, cols):
     """(qmin, qmax) saturation grids for a phase-split stage tile.
 
-    `rows_abs` is the (possibly traced) absolute-row index vector of the
-    tile; columns are static.  Residues absent from the phase map keep the
-    union-column bounds."""
+    `rows` is the ``(n, 1)`` absolute-row index column of the tile (it
+    may be traced), `cols` the ``(1, m)`` absolute-column index row.
+    Residues absent from the phase map keep the union-column bounds."""
     import jax.numpy as jnp
     my, mx = phase.lattice
-    # scalar-only construction (no captured constant arrays — the same
-    # code traces inside a pallas kernel)
-    rr = (rows_abs % my).reshape(-1, 1)
-    cc = (jnp.arange(W) % mx).reshape(1, -1)
-    qmin = jnp.full((rows_abs.shape[0], W), t.int_min, dtype=jnp.int64)
-    qmax = jnp.full((rows_abs.shape[0], W), t.int_max, dtype=jnp.int64)
+    rr = rows % my
+    cc = cols % mx
+    shape = (rows.shape[0], cols.shape[1])
+    qmin = jnp.full(shape, t.int_min, dtype=jnp.int64)
+    qmax = jnp.full(shape, t.int_max, dtype=jnp.int64)
     for (ry, rx), t_ph in sorted(phase.types.items()):
         mask = (rr == ry % my) & (cc == rx % mx)
         qmin = jnp.where(mask, t_ph.int_min, qmin)
@@ -200,31 +199,53 @@ def ingest_input(x, ls: LoweredStage, xp):
     return quantize_input(x, ls.t, dt, xp)
 
 
-def finish_intlinear(ls: LoweredStage, acc, rows_abs, W: int,
-                     container=None):
+def ingest_host(lp: LoweredPipeline, names: Sequence[str], img_of: Dict):
+    """Pipeline inputs -> stored input tiles, quantized on the host in
+    numpy (the oracle's arithmetic), so the device never sees f64.
+    Returns ``({name: container ndarray}, shared shape)``."""
+    buffers: Dict[str, np.ndarray] = {}
+    shape = None
+    for n in names:
+        x = np.asarray(img_of[n])
+        if x.ndim not in (2, 3):
+            raise LoweringError(
+                f"images must be (H, W) or (B, H, W); got {tuple(x.shape)}")
+        if shape is None:
+            shape = tuple(x.shape)
+        elif tuple(x.shape) != shape:
+            raise LoweringError("all pipeline inputs must share one shape; "
+                                f"got {shape} vs {x.shape}")
+        buffers[n] = np.asarray(ingest_input(x, lp.stages[n], np))
+    return buffers, shape
+
+
+def finish_intlinear(ls: LoweredStage, acc, rows, cols, container=None):
     """Accumulator -> saturated scaled-int tile (union + per-residue).
 
-    `container` overrides the stored dtype (must hold the clipped
-    range; the fused jnp program passes `fused_store_dtype`)."""
+    `rows`/`cols` are the tile's absolute row/column indices (see
+    `residue_bounds`).  `container` overrides the stored dtype (must
+    hold the clipped range; the fused jnp program passes
+    `fused_store_dtype`)."""
     import jax.numpy as jnp
     if ls.dyadic:
         q = rhe_shift(acc * ls.sm if ls.sm != 1 else acc, ls.t_shift)
     else:
         q = jnp.rint(acc.astype(jnp.float64) * ls.cscale)
     if ls.phase is not None:
-        qmin, qmax = residue_bounds(ls.phase, ls.t, rows_abs, W)
+        qmin, qmax = residue_bounds(ls.phase, ls.t, rows, cols)
         q = jnp.clip(q, qmin, qmax)
     else:
         q = jnp.clip(q, ls.t.int_min, ls.t.int_max)
     return q.astype(container if container is not None else store_dtype(ls))
 
 
-def snap_expr(ls: LoweredStage, raw, rows_abs, W: int, container=None):
+def snap_expr(ls: LoweredStage, raw, rows, cols, container=None):
     """Raw f64 stage tile -> stored tile (int grid or oracle-float).
 
-    `container` overrides the stored dtype on the integer path (must
-    hold the clipped range; the fused jnp program passes
-    `fused_store_dtype`); the float paths ignore it."""
+    `rows`/`cols` as in `finish_intlinear`.  `container` overrides the
+    stored dtype on the integer path (must hold the clipped range; the
+    fused jnp program passes `fused_store_dtype`); the float paths
+    ignore it."""
     import jax.numpy as jnp
     t = ls.t
     if t is None:
@@ -234,17 +255,15 @@ def snap_expr(ls: LoweredStage, raw, rows_abs, W: int, container=None):
         # oracle stores (union snap, then per-residue re-snap of raw)
         out = snap_float(raw, t, jnp)
         my, mx = ls.phase.lattice
-        rows = (rows_abs % my).reshape(-1, 1)
-        cols = (jnp.arange(W) % mx).reshape(1, -1)
         for (ry, rx), t_ph in sorted(ls.phase.types.items()):
-            mask = (rows == ry % my) & (cols == rx % mx)
+            mask = (rows % my == ry % my) & (cols % mx == rx % mx)
             out = jnp.where(mask, snap_float(raw, t_ph, jnp), out)
         return out
     if ls.store_float:                  # wide type: keep the oracle floats
         return snap_float(raw, t, jnp)
     q = jnp.rint(raw * (2.0 ** t.beta))
     if ls.phase is not None:
-        qmin, qmax = residue_bounds(ls.phase, t, rows_abs, W)
+        qmin, qmax = residue_bounds(ls.phase, t, rows, cols)
         q = jnp.clip(q, qmin, qmax)
     else:
         q = jnp.clip(q, t.int_min, t.int_max)
@@ -257,6 +276,15 @@ def dequant(ls: LoweredStage, tile):
     if ls.store_float:
         return tile
     return tile.astype(jnp.float64) * (2.0 ** -ls.t.beta)
+
+
+def dequant_host(ls: LoweredStage, tile) -> np.ndarray:
+    """`dequant` in numpy on a fetched container array — the oracle's own
+    arithmetic, so device programs need no f64 at their outputs."""
+    a = np.asarray(tile)
+    if ls.store_float:
+        return a
+    return a.astype(np.float64) * (2.0 ** -ls.t.beta)
 
 
 def dequant_f32(ls: LoweredStage, tile):
@@ -309,13 +337,12 @@ def compile_jnp(lp: LoweredPipeline,
     Images with a leading batch dimension — ``(B, H, W)`` instead of
     ``(H, W)`` — run as ONE `vmap`-batched program over the same fused
     forward.  Every op in the datapath is per-pixel (MACs, shifts,
-    clips, gathers; no cross-batch reduction anywhere), so the batched
+    clips, slices; no cross-batch reduction anywhere), so the batched
     program is bit-for-bit the per-image loop (pinned in
     tests/test_serving.py).
     """
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
     from repro.dsl.exec import _pad_inputs, _stage_out_shape, eval_expr
 
     outs = list(outputs or lp.pipeline.outputs)
@@ -360,8 +387,9 @@ def compile_jnp(lp: LoweredPipeline,
 
                 acc = accumulate_intlinear(
                     ls, tap_of, lambda: jnp.zeros((Hs, Ws), cdt))
-                rows_abs = jnp.arange(acc.shape[0])
-                q = finish_intlinear(ls, acc, rows_abs, acc.shape[1],
+                q = finish_intlinear(ls, acc,
+                                     jnp.arange(acc.shape[0])[:, None],
+                                     jnp.arange(acc.shape[1])[None, :],
                                      container=fused_store_dtype(ls))
                 tiles[name] = q
             else:
@@ -382,8 +410,9 @@ def compile_jnp(lp: LoweredPipeline,
                 sy, sx = st.stride
                 if sy > 1 or sx > 1:
                     raw = raw[::sy, ::sx]
-                rows_abs = jnp.arange(raw.shape[0])
-                tiles[name] = snap_expr(ls, raw, rows_abs, raw.shape[1],
+                tiles[name] = snap_expr(ls, raw,
+                                        jnp.arange(raw.shape[0])[:, None],
+                                        jnp.arange(raw.shape[1])[None, :],
                                         container=fused_store_dtype(ls))
             vals[name] = dequant(ls, tiles[name])
             shapes[name] = tuple(vals[name].shape)
@@ -399,7 +428,7 @@ def compile_jnp(lp: LoweredPipeline,
         with obs.span("exec.lowered", backend="jnp",
                       pipeline=lp.pipeline.name, outputs=len(outs)) as sp:
             imgs, in_names = normalize_images(lp, image)
-            with enable_x64():
+            with jax.enable_x64(True):
                 # container-dtype frames ship narrow (zero-copy ingest);
                 # everything else takes the f64 quantize path in-trace
                 def to_dev(im, n):
@@ -431,7 +460,8 @@ def compile_jnp(lp: LoweredPipeline,
         obs.runtime.record_env(res, lp, backend="jnp")
         return res
 
-    run.lowered = lp          # introspection hook for tests/benchmarks
+    run.lowered = lp          # introspection hooks for tests/benchmarks
+    run.forward = forward     # the per-image program `run` jits and vmaps
     return run
 
 

@@ -11,7 +11,7 @@ exact-integer bits, f64 for float-stored stages).  Narrow stitching
 preserves the bit-for-bit differential contract against the numpy
 oracle: the stored value was clipped into the container's range before
 the narrowing astype, loads widen losslessly, so the downstream
-island's clamped gathers over a materialized boundary read exactly the
+island's taps over a materialized boundary read exactly the
 values the oracle's padded geometry reads — in a quarter of the bytes
 where the plan proves 8-bit ranges.
 

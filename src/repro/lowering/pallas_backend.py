@@ -17,13 +17,13 @@ monolithic behavior).
 
 Per-stage datapaths are synthesized from each `LoweredStage`:
 
-  * `intlinear` — integer multiply-accumulate over clamped tap gathers
+  * `intlinear` — integer multiply-accumulate over static tap slices
     (int32, an int32 *pair* with one widening combine, or int64 —
     narrow-mode election, see `repro.lowering.ir`), finished by a
     round-half-even shift (dyadic scale) or one f64 multiply + rint,
     saturated per lattice residue where the plan carries phase types;
   * `expr`      — the oracle's expression tree replayed on dequantized
-    gathers (`dsl.exec.eval_expr`) in f64, or in f32 under a narrow-mode
+    taps (`dsl.exec.eval_expr`) in f64, or in f32 under a narrow-mode
     exactness proof, then snapped.
 
 Everything is bit-identical to `run_fixed(backend="numpy")` (see
@@ -33,24 +33,23 @@ equivalence spelled out in `kernels.stencil.kernel`, and island
 boundaries reproduce the oracle's stage values exactly because the
 stored representation IS the oracle's value grid).
 
-`interpret=None` (the default) resolves by capability detection:
-`interpret=False` automatically on a real TPU/GPU whose backend passes a
-one-time 64-bit probe (or when the pipeline needs no 64-bit datapath),
-with a graceful one-time `RuntimeWarning` fallback to interpreter mode
-everywhere else — so off-accelerator CI needs no TPU runner.
+`interpret=None` (the default) resolves by platform
+(`resolve_interpret`): the kernel runs natively on a TPU — where a plan
+that needs 64-bit datapaths raises `LoweringError` naming its stages —
+and in interpret mode, with a one-time `RuntimeWarning`, on hosts with
+no TPU (the CPU tests).
 """
 from __future__ import annotations
 
 from fractions import Fraction
 from typing import Dict, Optional, Sequence
 
-import numpy as np
-
 from repro import obs
 from repro.lowering import backends as B
-from repro.lowering.ir import LoweredPipeline, LoweredStage, LoweringError
+from repro.lowering.ir import (LoweredPipeline, LoweredStage, LoweringError,
+                               _election_notes)
 from repro.lowering.islands import Island, partition_islands
-from repro.lowering.schedule import Schedule, build_schedule
+from repro.lowering.schedule import Schedule, build_schedule, column_blocks
 
 # ---------------------------------------------------------------------------
 # capability detection
@@ -66,66 +65,52 @@ def _warn_once(msg: str) -> None:
     obs.warn_once(msg, stacklevel=4)
 
 
-_probe_cache: Dict[str, bool] = {}
+def stages_needing_64bit(lp: LoweredPipeline) -> list:
+    """Stages whose in-kernel datapath touches int64/f64, in topo order:
+    float-stored or wider-than-31-bit tiles, phase-split residue grids
+    (built in int64), int64 and int32-pair carriers (the pair combines in
+    int64), f64 finishes and f64 expression replays."""
+    out = []
+    for n, ls in lp.stages.items():
+        if ls.store_float \
+                or (ls.t is not None and ls.t.width > 31) \
+                or ls.phase is not None \
+                or (ls.kind == "intlinear"
+                    and (ls.carrier in ("int64", "int32pair")
+                         or not ls.dyadic)) \
+                or (ls.kind == "expr" and not ls.stage.is_input
+                    and ls.expr_dtype == "f64"):
+            out.append(n)
+    return out
 
 
 def needs_64bit(lp: LoweredPipeline) -> bool:
     """True when any stage's in-kernel datapath touches int64/f64."""
-    for ls in lp.stages.values():
-        if ls.store_float:
-            return True
-        if ls.t is not None and ls.t.width > 31:
-            return True
-        if ls.phase is not None:        # residue grids build in int64
-            return True
-        if ls.kind == "intlinear" and (ls.carrier == "int64"
-                                       or not ls.dyadic):
-            return True
-        if ls.kind == "expr" and not ls.stage.is_input \
-                and ls.expr_dtype == "f64":
-            return True
-    return False
-
-
-def supports_64bit(platform: str) -> bool:
-    """One-time probe: does this jax backend hold int64/f64 natively?"""
-    if platform in _probe_cache:
-        return _probe_cache[platform]
-    try:
-        import jax
-        import jax.numpy as jnp
-        from jax.experimental import enable_x64
-        with enable_x64():
-            i = jax.jit(lambda a: a.astype(jnp.int64) * ((1 << 40) + 1))(
-                jnp.arange(3, dtype=jnp.int32))
-            f = jax.jit(lambda a: a.astype(jnp.float64) * 2.0 ** -40)(
-                jnp.arange(3, dtype=jnp.int32))
-            ok = (i.dtype == jnp.int64 and int(i[2]) == 2 * ((1 << 40) + 1)
-                  and f.dtype == jnp.float64
-                  and float(f[1]) == 2.0 ** -40)
-    except Exception:
-        ok = False
-    _probe_cache[platform] = bool(ok)
-    return _probe_cache[platform]
+    return bool(stages_needing_64bit(lp))
 
 
 def resolve_interpret(lp: Optional[LoweredPipeline] = None) -> bool:
-    """Pick `interpret` for `pallas_call`: False on capable accelerators."""
+    """`interpret` for the fused kernel: native on a TPU, else interpret.
+
+    The TPU kernel compiler takes 32-bit datapaths only, so a plan that
+    needs 64-bit raises `LoweringError` there, naming its stages — it is
+    never sent to interpret mode on the chip.  Hosts without a TPU (the
+    CPU tests) run the kernel in interpret mode, with a one-time
+    `RuntimeWarning`."""
     import jax
     platform = jax.default_backend()
-    if platform in ("tpu", "gpu"):
-        if lp is not None and needs_64bit(lp) \
-                and not supports_64bit(platform):
-            _warn_once(
-                f"pallas: the pipeline needs 64-bit datapaths the "
-                f"{platform} backend lacks; running the fused kernel in "
-                f"interpret mode (narrow the plan with "
-                f"lower(..., datapath='narrow'))")
-            return True
+    if platform == "tpu":
+        wide = stages_needing_64bit(lp) if lp is not None else []
+        if wide:
+            notes = "\n".join(_election_notes(lp.pipeline.name, lp.stages))
+            raise LoweringError(
+                f"pallas: {lp.pipeline.name!r} needs 64-bit datapaths in "
+                f"stages {wide}; the TPU kernel takes 32-bit only (narrow "
+                f"the plan with lower(..., datapath='narrow')):\n{notes}")
         return False
     _warn_once(
-        f"pallas: no TPU/GPU accelerator (jax default_backend="
-        f"{platform!r}); the fused kernel runs in interpret mode")
+        f"pallas: no TPU (jax default_backend={platform!r}); the fused "
+        f"kernel runs in interpret mode")
     return True
 
 
@@ -149,21 +134,21 @@ def _compute_descriptor(lp: LoweredPipeline, name: str, ss):
     if ls.kind == "intlinear":
         cdt = B.carrier_dtype(ls.carrier)
 
-        def fn(tap, rows_abs, ls=ls, cdt=cdt, W=ss.W):
+        def fn(tap, rows, cols, ls=ls, cdt=cdt):
             acc = B.accumulate_intlinear(
                 ls,
                 lambda tp: tap(tp.stage, tp.dy, tp.dx).astype(cdt),
-                lambda: jnp.zeros((rows_abs.shape[0], W), cdt))
-            return B.finish_intlinear(ls, acc, rows_abs, W)
+                lambda: jnp.zeros((rows.shape[0], cols.shape[1]), cdt))
+            return B.finish_intlinear(ls, acc, rows, cols)
     else:
         deq = B.dequant_f32 if ls.expr_dtype == "f32" else B.dequant
 
-        def fn(tap, rows_abs, ls=ls, deq=deq, W=ss.W):
+        def fn(tap, rows, cols, ls=ls, deq=deq):
             def ref(stage, dy, dx):
                 return deq(lp.stages[stage], tap(stage, dy, dx))
 
             raw = eval_expr(st.expr, ref, params, jnp, jnp.where)
-            return B.snap_expr(ls, raw, rows_abs, W)
+            return B.snap_expr(ls, raw, rows, cols)
 
     return dict(kind="compute", name=name, step=ss.step, lo=ss.lo, L=ss.L,
                 H=ss.H, W=ss.W, dtype=B.store_dtype(ls),
@@ -180,12 +165,20 @@ def island_program(lp: LoweredPipeline, isl: Island) -> list:
     identical closures by construction."""
     program = []
     slot = {n: i for i, n in enumerate(isl.inputs)}
+    blocks = column_blocks(lp, isl.schedule.order)
     for n in isl.schedule.order:
         ss = isl.schedule.stages[n]
         if n in slot:
-            program.append(_input_descriptor(n, lp.stages[n], ss, slot[n]))
+            d = _input_descriptor(n, lp.stages[n], ss, slot[n])
         else:
-            program.append(_compute_descriptor(lp, n, ss))
+            d = _compute_descriptor(lp, n, ss)
+        d["M"] = blocks[n]
+        d["Wb"] = -(-ss.W // blocks[n])
+        program.append(d)
+    tapped = {i for d in program if d["kind"] == "compute"
+              for i in d["inputs"]}
+    for d in program:
+        d["tapped"] = d["name"] in tapped
     for out_slot, n in enumerate(isl.outputs):
         for d in program:
             if d["name"] == n:
@@ -201,18 +194,13 @@ def compile_pallas(lp: LoweredPipeline,
                    outputs: Optional[Sequence[str]] = None,
                    interpret: Optional[bool] = None,
                    tile_rows: Optional[int] = None,
-                   islands: bool = True,
-                   prefetch: Optional[bool] = None) -> B.Executor:
+                   islands: bool = True) -> B.Executor:
     """Shape-specialized executor: the island plan + kernels are built
     (and cached) per input shape on first call.
 
     `islands=False` opts out of partitioning: the whole DAG must band-
     schedule as one program or `LoweringError` is raised (the historical
     contract, for callers that want to catch-and-fallback themselves).
-
-    `prefetch` (default auto: on for native TPU runs) selects the
-    double-buffered two-slot band DMA so each island overlaps the next
-    band's HBM->VMEM copy with the current band's compute.
     """
     from repro.kernels.stencil.kernel import fused_pipeline
 
@@ -220,13 +208,15 @@ def compile_pallas(lp: LoweredPipeline,
     order = B.needed_stages(lp, outs)
     input_names = [n for n in order if lp.stages[n].stage.is_input]
     interp = resolve_interpret(lp) if interpret is None else interpret
+    # 64-bit datapaths exist only off-TPU (interpret mode); a 32-bit plan
+    # traces without x64 so no 64-bit constant reaches the kernel
+    x64 = needs_64bit(lp)
     cache: Dict[tuple, list] = {}
 
     def compile_island(isl: Island, batch: Optional[int]):
         return fused_pipeline(island_program(lp, isl),
                               grid=isl.schedule.grid,
-                              interpret=interp, batch=batch,
-                              prefetch=prefetch)
+                              interpret=interp, batch=batch)
 
     def build(shape):
         # a leading batch dim becomes the kernels' outer grid axis; the
@@ -248,8 +238,8 @@ def compile_pallas(lp: LoweredPipeline,
         return [(isl, compile_island(isl, batch)) for isl in isls]
 
     def run(image, params_override=None):
+        import jax
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
         if params_override is not None and \
                 dict(params_override) != lp.params:
             raise ValueError("params are baked at compile time; re-lower "
@@ -258,26 +248,11 @@ def compile_pallas(lp: LoweredPipeline,
         img_of = dict(zip(lp.pipeline.input_stages(), imgs))
         with obs.span("exec.pallas", backend="pallas",
                       pipeline=lp.pipeline.name, outputs=len(outs)) as sp:
-            with enable_x64():
-                buffers: Dict[str, object] = {}
-                shape = None
-                for n in input_names:
-                    x = jnp.asarray(np.asarray(img_of[n]))
-                    if x.ndim not in (2, 3):
-                        raise LoweringError(
-                            f"images must be (H, W) or (B, H, W); got "
-                            f"{tuple(x.shape)}")
-                    if shape is None:
-                        shape = tuple(x.shape)
-                    elif tuple(x.shape) != shape:
-                        raise LoweringError("all pipeline inputs must share "
-                                            f"one shape; got {shape} vs "
-                                            f"{x.shape}")
-                    # container-dtype frames are pre-quantized stored
-                    # tiles (zero-copy); others quantize from f64
-                    buffers[n] = B.ingest_input(x, lp.stages[n], jnp)
-                if len(shape) == 3:
-                    sp.set(batch=int(shape[0]))
+            buffers, shape = B.ingest_host(lp, input_names, img_of)
+            if len(shape) == 3:
+                sp.set(batch=int(shape[0]))
+            with jax.enable_x64(x64):
+                buffers = {n: jnp.asarray(a) for n, a in buffers.items()}
                 if shape not in cache:
                     sp.set(kernel_cache="miss")
                     cache[shape] = build(shape)
@@ -300,14 +275,14 @@ def compile_pallas(lp: LoweredPipeline,
                                           call(*[buffers[n]
                                                  for n in isl.inputs])):
                             buffers[n] = arr
-                res = {n: np.asarray(B.dequant(lp.stages[n], buffers[n]))
-                       for n in outs}
+            res = {n: B.dequant_host(lp.stages[n], buffers[n]) for n in outs}
         # fused kernels: intermediates never leave their island's bands,
         # so telemetry covers the materialized boundaries + outputs only
         obs.runtime.record_env(res, lp, backend="pallas")
         return res
 
     run.lowered = lp
+    run.interpret = interp    # False on a TPU: the kernel runs natively
     return run
 
 

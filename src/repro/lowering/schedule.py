@@ -11,7 +11,9 @@ same window: tile `i` of stage `s` covers rows
 which works exactly when every per-stage row rate `r_s` (output rows per
 *base* row, an exact rational through stride/upsample chains) times the
 base tile height `T` is an integer.  The core solver picks the smallest
-such `T` dividing the base height, then runs one backward span pass
+such `T` whose output and input bands are whole 8-row vector tiles (the
+grid is ``cdiv(H, T)``: the last band may be ragged), then runs one
+backward span pass
 computing (lo, hi) per stage from its consumers' needs — the tap-shifted,
 rate-scaled union:
 
@@ -35,8 +37,8 @@ Two entry points share the core:
   schedules at rate 1 relative to itself.
 
 `single_tile_schedule` is the universal escape hatch: one grid step whose
-band is each stage's full height.  It is always valid (the kernel's
-clamped gathers degenerate to whole-array gathers), so islands that
+band is each stage's full height.  It is always valid (the kernel's tap
+clamps become the oracle's edge clamps), so islands that
 cannot be banded — rate-inexact heights, halos deeper than any aligned
 tile — still fuse instead of falling back to the jnp program.
 """
@@ -47,6 +49,7 @@ from fractions import Fraction
 from math import gcd
 from typing import Dict, List, Optional, Set, Tuple
 
+from repro.kernels.stencil.kernel import ROW_ALIGN
 from repro.lowering.ir import LoweredPipeline, LoweringError
 
 
@@ -108,6 +111,49 @@ def row_rates(lp: LoweredPipeline) -> Dict[str, Fraction]:
     return rates
 
 
+def column_blocks(lp: LoweredPipeline, names: List[str]) -> Dict[str, int]:
+    """Column blocks per stage for a fused program over `names`.
+
+    A stage at column rate ``r`` (columns per pipeline-input column,
+    through every stride/upsample) is held as ``M = r*K`` blocks, block
+    ``p`` lane ``q`` being column ``q*M + p``; ``K`` is the smallest
+    factor making every ``M`` integral.  Then a consumer's block always
+    reads whole parent blocks at a static lane shift
+    (``M_c * sx / ux == M_p``), so column decimation and upsampling
+    never shuffle lanes.  Raises `LoweringError` when a stage mixes
+    inputs at different column rates.
+    """
+    need, stack = set(), list(names)
+    while stack:
+        n = stack.pop()
+        if n not in need:
+            need.add(n)
+            stack.extend(lp.stages[n].stage.inputs)
+    rates: Dict[str, Fraction] = {}
+    for name in lp.order:
+        if name not in need:
+            continue
+        st = lp.stages[name].stage
+        if st.is_input:
+            rates[name] = Fraction(1)
+            continue
+        rs = {rates[i] for i in st.inputs}
+        if len(rs) != 1:
+            raise LoweringError(
+                f"stage {name!r} mixes inputs at different column rates "
+                f"{sorted(map(str, rs))}; no column-block layout exists")
+        rates[name] = rs.pop() * st.upsample[1] / st.stride[1]
+    K = 1
+    for n in names:
+        d = rates[n].denominator
+        K = K * d // gcd(K, d)
+    blocks = {n: int(rates[n] * K) for n in names}
+    g = 0
+    for m in blocks.values():
+        g = gcd(g, m)
+    return {n: m // g for n, m in blocks.items()}
+
+
 def island_rates(lp: LoweredPipeline, compute: List[str],
                  ext_inputs: List[str],
                  shapes: Dict[str, Tuple[int, int]]
@@ -142,6 +188,7 @@ def island_rates(lp: LoweredPipeline, compute: List[str],
     return base, rates
 
 
+from repro.kernels.stencil.kernel import ROW_ALIGN  # noqa: E402
 def _schedule_core(lp: LoweredPipeline, shapes: Dict[str, Tuple[int, int]],
                    order: List[str], outputs: List[str],
                    H_base: int, rates: Dict[str, Fraction],
@@ -150,7 +197,13 @@ def _schedule_core(lp: LoweredPipeline, shapes: Dict[str, Tuple[int, int]],
     """Shared tile search + backward span pass over `order` (topo).
 
     `ext` marks stages treated as materialized inputs (no tap recursion
-    past them); `H_base` / `rates` anchor the step arithmetic.
+    past them); `H_base` / `rates` anchor the step arithmetic.  The grid
+    is ``cdiv(H_base, T)``: the last band may be ragged.  A tile height
+    is valid when every output and input stage advances a multiple of
+    `ROW_ALIGN` rows per band (aligned output blocks and input DMA
+    windows; or one band covers the image) and every stage's band
+    overlaps its stage in the first and the last band (so edge
+    replication finds the edge rows inside the tile).
     """
     base = 1
     for name in order:
@@ -159,6 +212,7 @@ def _schedule_core(lp: LoweredPipeline, shapes: Dict[str, Tuple[int, int]],
 
     def try_tile(T: int) -> Optional[Schedule]:
         steps = {n: int(rates[n] * T) for n in order}
+        grid = -(-H_base // T)
         lo: Dict[str, Optional[int]] = {
             n: 0 if n in outputs else None for n in order}
         hi: Dict[str, Optional[int]] = {
@@ -184,28 +238,31 @@ def _schedule_core(lp: LoweredPipeline, shapes: Dict[str, Tuple[int, int]],
                 continue
             s = StageSched(step=steps[n], lo=lo[n], hi=hi[n],
                            H=shapes[n][0], W=shapes[n][1])
-            if s.step < 1 or s.L > s.H:
+            if s.step < 1 or s.L > s.H or s.hi < 1 \
+                    or (grid - 1) * s.step + s.lo > s.H - 1:
+                return None
+            if grid > 1 and (n in outputs or n in ext) \
+                    and s.step % ROW_ALIGN:
                 return None
             stages[n] = s
-        return Schedule(grid=H_base // T, tile_rows=T, stages=stages,
+        return Schedule(grid=grid, tile_rows=T, stages=stages,
                         order=[n for n in order if n in stages])
 
     if tile_rows is not None:
-        if tile_rows % base or H_base % tile_rows:
+        if tile_rows % base or tile_rows > H_base:
             raise LoweringError(
                 f"tile_rows={tile_rows} must be a multiple of {base} "
-                f"and divide H={H_base}")
+                f"and at most H={H_base}")
         sched = try_tile(tile_rows)
         if sched is None:
             raise LoweringError(
-                f"tile_rows={tile_rows}: a stage's band would exceed its "
-                f"full height; use a larger tile")
+                f"tile_rows={tile_rows}: an output band is not a multiple "
+                f"of {ROW_ALIGN} rows, or a stage's band would exceed its "
+                f"full height")
         return sched
 
-    candidates = sorted(T for T in range(base, H_base + 1, base)
-                        if H_base % T == 0)
     best = None
-    for T in candidates:
+    for T in range(base, H_base + 1, base):
         sched = try_tile(T)
         if sched is None:
             continue
@@ -214,7 +271,7 @@ def _schedule_core(lp: LoweredPipeline, shapes: Dict[str, Tuple[int, int]],
             break
     if best is None:
         raise LoweringError(
-            f"no lattice-aligned tile height divides H={H_base} "
+            f"no lattice-aligned tile height fits H={H_base} "
             f"(phase modulus {base}, halos too deep for every candidate)")
     return best
 
@@ -278,7 +335,7 @@ def single_tile_schedule(lp: LoweredPipeline,
     """Degenerate one-tile schedule: every band is the full stage height.
 
     Always valid: with `grid=1`, `step=H`, `lo=0`, `hi=H` the fused
-    kernel's clamped band copies and tap gathers read exactly the rows
+    kernel's band copies and clamped tap slices read exactly the rows
     the oracle's padded geometry reads, for any stride/upsample/height
     combination — including rate-inexact (ceil-divided) heights.
     """

@@ -3,9 +3,11 @@
 The pallas backend walks each rate island's row-band schedule serially
 down the image; this backend distributes the *same* band walk across
 devices: a 1-D mesh (`launch.mesh.make_band_mesh`, axis ``"band"``)
-splits each island's grid into contiguous runs of ``grid // n_shards``
-bands, every device executes its run with the island's intermediates
-device-local, and the per-shard output rows concatenate back into the
+splits each island's grid into contiguous runs of ``k = cdiv(grid,
+n_shards)`` bands (the grid pads up to ``k * n_shards``; rows past the
+image are computed and dropped), every device executes its run with
+the island's intermediates device-local, and the per-shard output rows
+concatenate back into the
 full stage arrays along the lattice-aligned band axis — bands are the
 partition unit exactly as they are the VMEM-residency unit in the fused
 kernel, and island boundaries stay materialized (replicated) buffers
@@ -14,69 +16,134 @@ just like the HBM stitching.
 Bit-exactness is by construction, not by re-derivation: the shard body
 executes the SAME stage descriptors (`pallas_backend.island_program`)
 through the SAME band geometry (`kernels.stencil.kernel.eval_band`) as
-the fused Pallas kernel, with `load_band` a clamped `dynamic_slice` on
-the replicated input instead of an HBM-ref slice.  Device ``d`` computes
-band steps ``[d*k, (d+1)*k)`` via `lax.axis_index`; since every band's
+the fused Pallas kernel, with `load_band` a `dynamic_slice` on the
+replicated row-padded input instead of the kernel's band DMA.  Device
+``d`` computes band steps ``[d*k, (d+1)*k)`` via `lax.axis_index`;
+since every band's
 value depends only on the (replicated) island inputs, the concatenated
 result is bit-identical to the serial walk — pinned against the numpy
 oracle in tests/test_serving.py, batched and phase-split plans included.
 
-Fallbacks (one-time `RuntimeWarning` via `repro.obs.warn_once`):
+Single-tile islands (grid == 1 cannot split) run the identical band
+walk unsharded on the local device, with a one-time `RuntimeWarning`
+via `repro.obs.warn_once` — never a different datapath, so exactness is
+unaffected.
 
-  * an island whose grid does not divide over the mesh, and
-  * single-tile islands (grid == 1 cannot split),
-
-run the identical band walk unsharded on the local device — never a
-different datapath, so exactness is unaffected.
-
-Images with a leading batch dimension ``(B, H, W)`` vmap the shard body
-over the batch axis inside `shard_map` (bands stay the partition unit;
-the batch axis is replicated).
+Images with a leading batch dimension ``(B, H, W)`` vmap each band's
+evaluation over the batch axis inside `shard_map` (bands stay the
+partition unit; the batch axis is replicated).
 """
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Sequence
-
-import numpy as np
+from typing import Dict, Optional, Sequence
 
 from repro import obs
 from repro.lowering import backends as B
-from repro.lowering.ir import LoweredPipeline, LoweringError
+from repro.lowering.ir import LoweredPipeline
 from repro.lowering.islands import Island, partition_islands
-from repro.lowering.pallas_backend import island_program
+from repro.lowering.pallas_backend import island_program, needs_64bit
 
 
-def _band_walk(program: Sequence[dict], k: int, base_of):
+def _band_walk(program: Sequence[dict], k: int, nbands: int, base_of,
+               batched: bool):
     """f(*inputs) -> tuple of output stage arrays for `k` band steps.
 
-    `base_of()` yields the first band index of this walk — 0 for the
-    serial fallback, ``axis_index("band") * k`` inside a shard.  The
-    loop over the k steps is a static python loop (k is small: bands
-    per shard), each step re-running `eval_band` — the one shared
-    definition of the tap/clamp geometry.
+    Inputs are the island inputs in row-padded column blocks
+    (`kernels.stencil.kernel.to_blocks` for an `nbands` walk), with a
+    leading batch dim when `batched`; outputs are ``([B,] M, k*step,
+    Wb)`` column blocks.  `base_of()` yields the first band index of
+    this walk — 0 for the serial walk, ``axis_index("band") * k`` inside
+    a shard.  The k steps run as one `lax.map`, each step slicing its
+    bands (outside any vmap: a batched dynamic slice would become a
+    gather) and re-running `eval_band` — the one shared definition of
+    the tap/clamp geometry — per image.
     """
     import jax
     import jax.numpy as jnp
-    from repro.kernels.stencil.kernel import band_output, eval_band
+    from repro.kernels.stencil.kernel import (band_output, eval_band,
+                                              input_pads)
 
+    ins = sorted((d for d in program if d["kind"] == "input"),
+                 key=lambda d: d["in_slot"])
     outs = sorted((d for d in program if d.get("out_slot") is not None),
                   key=lambda d: d["out_slot"])
 
     def fn(*inputs):
-        def load_band(d, start):
-            return jax.lax.dynamic_slice_in_dim(
-                inputs[d["in_slot"]], start, d["L"], axis=0)
-
         base = base_of()
-        chunks: Dict[str, List] = {d["name"]: [] for d in outs}
-        for j in range(k):
-            tiles = eval_band(program, base + j, load_band)
-            for d in outs:
-                chunks[d["name"]].append(band_output(d, tiles[d["name"]]))
-        return tuple(jnp.concatenate(chunks[d["name"]], axis=0)
-                     for d in outs)
+
+        def step(j):
+            i = base + j
+            bands = [jax.lax.dynamic_slice_in_dim(
+                         x, i * d["step"] + d["lo"] + input_pads(d, nbands)[0],
+                         d["L"], axis=x.ndim - 2)[..., :d["Wb"]]
+                     for x, d in zip(inputs, ins)]
+
+            def one(*bands):
+                tiles = eval_band(program, i,
+                                  lambda d, start: bands[d["in_slot"]],
+                                  nbands)
+                return tuple(jnp.stack([band_output(d, t)
+                                        for t in tiles[d["name"]]])
+                             for d in outs)
+
+            return jax.vmap(one)(*bands) if batched else one(*bands)
+
+        out = jax.lax.map(step, jnp.arange(k, dtype=jnp.int32))
+        # (k, [B,] M, step, Wb) -> ([B,] M, k*step, Wb)
+        res = []
+        for b, d in zip(out, outs):
+            b = jnp.moveaxis(b, 0, -3)
+            res.append(b.reshape(b.shape[:-3] + (k * d["step"], d["Wb"])))
+        return tuple(res)
 
     return fn
+
+
+def compile_island(lp: LoweredPipeline, isl: Island, mesh,
+                   batch: Optional[int]):
+    """Jitted ``f(*island inputs) -> island outputs`` over `mesh`, and
+    whether it is band-sharded (single-tile islands are not)."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    from repro.kernels.stencil.kernel import from_blocks, to_blocks
+
+    S = mesh.shape["band"]
+    grid = isl.schedule.grid
+    sharded = not isl.single_tile
+    if not sharded:
+        obs.warn_once(
+            f"sharded: island {isl.idx} of {lp.pipeline.name!r} falls "
+            f"back to the serial band walk (single-tile island)")
+    # the band grid pads up to a multiple of the mesh: the extra
+    # bands compute rows past the image, which are dropped
+    k = -(-grid // S) if sharded else grid
+    nbands = k * S if sharded else grid
+    program = island_program(lp, isl)
+    ins = sorted((d for d in program if d["kind"] == "input"),
+                 key=lambda d: d["in_slot"])
+    outs_d = sorted((d for d in program
+                     if d.get("out_slot") is not None),
+                    key=lambda d: d["out_slot"])
+    body = _band_walk(program, k, nbands,
+                      (lambda: jax.lax.axis_index("band") * k) if sharded
+                      else (lambda: 0), batched=batch is not None)
+    if sharded:
+        # every input is replicated; outputs shard their band-built
+        # row axis over the mesh
+        row = P(None, "band", None) if not batch \
+            else P(None, None, "band", None)
+        body = jax.shard_map(body, mesh=mesh,
+                             in_specs=(P(),) * len(ins),
+                             out_specs=(row,) * len(outs_d),
+                             check_vma=False)
+
+    def run(*arrays):
+        out = body(*[to_blocks(a, d, nbands)
+                     for a, d in zip(arrays, ins)])
+        return tuple(from_blocks(y, d) for y, d in zip(out, outs_d))
+
+    return jax.jit(run), sharded
 
 
 def compile_sharded(lp: LoweredPipeline,
@@ -89,68 +156,25 @@ def compile_sharded(lp: LoweredPipeline,
     jitted shard programs are built (and cached) per input shape on
     first call.
     """
-    import jax
-    from jax.sharding import PartitionSpec as P
-
     from repro.launch.mesh import make_band_mesh
-    from repro.launch.sharding import spec_for
 
     outs = list(outputs or lp.pipeline.outputs)
     order = B.needed_stages(lp, outs)
     input_names = [n for n in order if lp.stages[n].stage.is_input]
     cache: Dict[tuple, list] = {}
-
-    def compile_island(isl: Island, mesh, batch: Optional[int]):
-        try:
-            from jax.experimental.shard_map import shard_map
-        except ImportError:            # newer jax: promoted out of experimental
-            from jax.sharding import shard_map   # type: ignore
-        program = island_program(lp, isl)
-        S = mesh.shape["band"]
-        grid = isl.schedule.grid
-        outs_d = sorted((d for d in program
-                         if d.get("out_slot") is not None),
-                        key=lambda d: d["out_slot"])
-        if isl.single_tile or grid % S != 0:
-            reason = ("single-tile island" if isl.single_tile else
-                      f"grid {grid} does not divide over {S} shards")
-            obs.warn_once(
-                f"sharded: island {isl.idx} of {lp.pipeline.name!r} falls "
-                f"back to the serial band walk ({reason}); pad the image "
-                f"or shrink the mesh for full band sharding")
-            body = _band_walk(program, grid, lambda: 0)
-            fn = jax.jit(jax.vmap(body) if batch else body)
-            return fn, False
-        k = grid // S
-        body = _band_walk(
-            program, k, lambda: jax.lax.axis_index("band") * k)
-        if batch:
-            body = jax.vmap(body)
-        # every input is replicated; outputs shard their band-built row
-        # axis — spec_for maps the "band_rows" logical axis onto the mesh
-        # (grid % S == 0 implies row divisibility: H = grid * step)
-        row_axes = ("band_rows",) if not batch else (None, "band_rows")
-        out_specs = tuple(
-            spec_for((d["H"], d["W"]) if not batch
-                     else (batch, d["H"], d["W"]),
-                     row_axes + (None,), mesh)
-            for d in outs_d)
-        fn = jax.jit(shard_map(body, mesh=mesh,
-                               in_specs=(P(),) * len(isl.inputs),
-                               out_specs=out_specs, check_rep=False))
-        return fn, True
+    x64 = needs_64bit(lp)
 
     def build(shape, mesh):
         batch = shape[0] if len(shape) == 3 else None
         in_shape = tuple(shape[-2:])
         plan = partition_islands(lp, in_shape, outputs=outs,
                                  tile_rows=tile_rows)
-        return [(isl,) + compile_island(isl, mesh, batch)
+        return [(isl,) + compile_island(lp, isl, mesh, batch)
                 for isl in plan.islands]
 
     def run(image, params_override=None):
+        import jax
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
         if params_override is not None and \
                 dict(params_override) != lp.params:
             raise ValueError("params are baked at compile time; re-lower "
@@ -161,26 +185,13 @@ def compile_sharded(lp: LoweredPipeline,
         with obs.span("exec.sharded", backend="sharded",
                       pipeline=lp.pipeline.name, outputs=len(outs),
                       shards=m.shape["band"]) as sp:
-            with enable_x64():
-                buffers: Dict[str, object] = {}
-                shape = None
-                for n in input_names:
-                    x = jnp.asarray(np.asarray(img_of[n]))
-                    if x.ndim not in (2, 3):
-                        raise LoweringError(
-                            f"images must be (H, W) or (B, H, W); got "
-                            f"{tuple(x.shape)}")
-                    if shape is None:
-                        shape = tuple(x.shape)
-                    elif tuple(x.shape) != shape:
-                        raise LoweringError(
-                            "all pipeline inputs must share one shape; "
-                            f"got {shape} vs {x.shape}")
-                    # narrow replicated inputs: container-dtype frames
-                    # ship as-is across the mesh (zero-copy ingest)
-                    buffers[n] = B.ingest_input(x, lp.stages[n], jnp)
-                if len(shape) == 3:
-                    sp.set(batch=int(shape[0]))
+            buffers, shape = B.ingest_host(lp, input_names, img_of)
+            if len(shape) == 3:
+                sp.set(batch=int(shape[0]))
+            with jax.enable_x64(x64):
+                # narrow replicated inputs: container-dtype frames ship
+                # as-is across the mesh (zero-copy ingest)
+                buffers = {n: jnp.asarray(a) for n, a in buffers.items()}
                 key = shape + (m.shape["band"],)
                 if key not in cache:
                     sp.set(kernel_cache="miss")
@@ -201,8 +212,7 @@ def compile_sharded(lp: LoweredPipeline,
                                           call(*[buffers[i]
                                                  for i in isl.inputs])):
                             buffers[n] = arr
-                res = {n: np.asarray(B.dequant(lp.stages[n], buffers[n]))
-                       for n in outs}
+            res = {n: B.dequant_host(lp.stages[n], buffers[n]) for n in outs}
         # like pallas: intermediates never materialize, telemetry covers
         # island boundaries + outputs only
         obs.runtime.record_env(res, lp, backend="sharded")

@@ -591,13 +591,13 @@ def test_prequantized_fractional_input_matches_f64_path():
                          [BENCHES[0], BENCHES[3]], ids=["usm", "dus_ext"])
 def test_pallas_prefetch_double_buffer_bit_exact(name, build, params,
                                                  n_in, shape):
-    """Forced double-buffered band prefetch (interpret mode emulates the
+    """The double-buffered band DMA kernel (interpret mode emulates the
     DMA copies + semaphores) stays bit-identical to the numpy oracle,
     single-frame and batched."""
     pipe = build()
     types = _types_for(pipe)
     lp = lower(pipe, types, params=params)
-    run = compile_backend(lp, "pallas", prefetch=True, interpret=True)
+    run = compile_backend(lp, "pallas", interpret=True)
     img = _img(shape, seed=29)
     oracle = run_fixed(pipe, img, types, params)
     outs = run(img)
