@@ -458,7 +458,7 @@ def _fused_kernel(*refs, program: Sequence[Dict], n_in: int, n_out: int,
     _write_outputs(program, tiles, out_refs, batched)
 
 
-def fused_pipeline(program: Sequence[Dict], grid: int,
+def fused_pipeline(program: Sequence[Dict], grid: int, name: str,
                    interpret: bool = True,
                    batch: int | None = None) -> Callable:
     """Compile a band-scheduled stage program into one pallas_call.
@@ -471,7 +471,8 @@ def fused_pipeline(program: Sequence[Dict], grid: int,
     outer batch axis — ``grid=(batch, bands)`` — so every (image, band)
     pair is one grid step of the same VMEM-resident band program.  The
     last band may be ragged: output blocks past a stage's height are
-    dropped on write.
+    dropped on write.  `name` names the kernel in compiled programs and
+    device traces.
     """
     ins = sorted((d for d in program if d["kind"] == "input"),
                  key=lambda d: d["in_slot"])
@@ -507,7 +508,7 @@ def fused_pipeline(program: Sequence[Dict], grid: int,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
-        name="fused_band_island",
+        name=name,
     )
 
     def run(*arrays):

@@ -287,6 +287,44 @@ def dequant_host(ls: LoweredStage, tile) -> np.ndarray:
     return a.astype(np.float64) * (2.0 ** -ls.t.beta)
 
 
+def run_on_device(lp: LoweredPipeline, outs: Sequence[str],
+                  to_device: Callable[[], object],
+                  dispatch: Callable[[object], Dict[str, object]],
+                  dequantize: bool = True) -> Dict[str, np.ndarray]:
+    """The fused executors' host path around one device program.
+
+    Each step is a child span of the caller's ``exec.*`` span:
+
+      * ``exec.h2d`` — `to_device()`: host ingest and the copies of the
+        inputs to the device, waited on until they have landed (the
+        copies are asynchronous; the program needs its inputs anyway, so
+        the wait moves no transfer and keeps the copy out of
+        ``exec.device_wait``);
+      * ``exec.dispatch`` — `dispatch(args)`: enqueue the device program
+        (and build it on a cold shape); returns ``{stage: device array}``;
+      * ``exec.device_wait`` — block until the outputs in `outs` exist.
+        The first copy would block there anyway, so waiting first changes
+        no ordering; it gives the device's time its own span;
+      * ``exec.d2h`` — one `np.asarray` copy per output;
+      * ``exec.dequant`` — with `dequantize`, `dequant_host` of each
+        stored container tile to the oracle's f64 values (backends that
+        dequantize on the device pass False and skip this span).
+    """
+    import jax
+    with obs.span("exec.h2d"):
+        args = jax.block_until_ready(to_device())
+    with obs.span("exec.dispatch"):
+        out = dispatch(args)
+    with obs.span("exec.device_wait"):
+        out = jax.block_until_ready({n: out[n] for n in outs})
+    with obs.span("exec.d2h"):
+        res = {n: np.asarray(out[n]) for n in outs}
+    if not dequantize:
+        return res
+    with obs.span("exec.dequant"):
+        return {n: dequant_host(lp.stages[n], a) for n, a in res.items()}
+
+
 def dequant_f32(ls: LoweredStage, tile):
     """Stored tile -> the *exact* f32 stage value (narrow-mode f32 path).
 
@@ -428,19 +466,17 @@ def compile_jnp(lp: LoweredPipeline,
         with obs.span("exec.lowered", backend="jnp",
                       pipeline=lp.pipeline.name, outputs=len(outs)) as sp:
             imgs, in_names = normalize_images(lp, image)
-            with jax.enable_x64(True):
-                # container-dtype frames ship narrow (zero-copy ingest);
-                # everything else takes the f64 quantize path in-trace
-                def to_dev(im, n):
-                    a = np.asarray(im)
-                    ls = lp.stages[n]
-                    if ls.t is not None \
-                            and a.dtype == np.dtype(store_dtype(ls)):
-                        return jnp.asarray(a)
-                    return jnp.asarray(a, dtype=jnp.float64)
 
-                arrs = tuple(to_dev(im, n)
-                             for im, n in zip(imgs, in_names))
+            # container-dtype frames ship narrow (zero-copy ingest);
+            # everything else takes the f64 quantize path in-trace
+            def to_dev(im, n):
+                a = np.asarray(im)
+                ls = lp.stages[n]
+                if ls.t is not None and a.dtype == np.dtype(store_dtype(ls)):
+                    return jnp.asarray(a)
+                return jnp.asarray(a, dtype=jnp.float64)
+
+            def dispatch(arrs):
                 ndims = {a.ndim for a in arrs}
                 if ndims == {3}:          # leading batch dim: vmap program
                     if len({a.shape[0] for a in arrs}) != 1:
@@ -448,14 +484,20 @@ def compile_jnp(lp: LoweredPipeline,
                             "batched inputs must share one batch size; got "
                             f"{[a.shape for a in arrs]}")
                     sp.set(batch=int(arrs[0].shape[0]))
-                    out = vjitted(*arrs)
-                elif ndims == {2}:
-                    out = jitted(*arrs)
-                else:
-                    raise LoweringError(
-                        f"images must all be (H, W) or all (B, H, W); got "
-                        f"{[a.shape for a in arrs]}")
-                res = {k: np.asarray(v) for k, v in out.items()}
+                    return vjitted(*arrs)
+                if ndims == {2}:
+                    return jitted(*arrs)
+                raise LoweringError(
+                    f"images must all be (H, W) or all (B, H, W); got "
+                    f"{[a.shape for a in arrs]}")
+
+            with jax.enable_x64(True):
+                # outputs are dequantized to f64 inside the program
+                res = run_on_device(
+                    lp, outs,
+                    lambda: tuple(to_dev(im, n)
+                                  for im, n in zip(imgs, in_names)),
+                    dispatch, dequantize=False)
         # read-only post-processing: never feeds back into the computation
         obs.runtime.record_env(res, lp, backend="jnp")
         return res

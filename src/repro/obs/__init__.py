@@ -17,7 +17,7 @@ telemetry), `report` (per-stage summary tables, also a CLI:
 """
 from .tracer import (            # noqa: F401
     CounterGroup, Span, Tracer, active_tracer, all_counters, disable,
-    enable, event, gauge, is_enabled, runtime_ranges_enabled, span,
+    enable, event, is_enabled, runtime_ranges_enabled, span,
     tracing,
 )
 from .exporters import (         # noqa: F401
@@ -29,7 +29,7 @@ from . import runtime            # noqa: F401
 
 __all__ = [
     "CounterGroup", "Span", "Tracer", "active_tracer", "all_counters",
-    "disable", "enable", "event", "gauge", "is_enabled", "load_jsonl",
+    "disable", "enable", "event", "is_enabled", "load_jsonl",
     "reset_warn_once", "runtime", "runtime_ranges_enabled", "span",
     "to_chrome_trace", "to_jsonl_records", "tracing", "warn_once",
     "write_chrome_trace", "write_jsonl",

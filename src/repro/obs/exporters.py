@@ -4,14 +4,13 @@ Two serializations of the same `Tracer` contents (docs/observability.md):
 
   * **JSONL** — one JSON object per line, machine-first.  Spans carry
     `{"kind": "span", "name", "id", "parent", "ts_us", "dur_us",
-    "thread", "attrs"}`; instant events and gauges keep their `kind`;
-    the final line is a `{"kind": "counters"}` snapshot of every
-    registered `CounterGroup`.  `repro.obs.report` and the tests consume
-    this form via `load_jsonl`.
+    "thread", "attrs"}`; instant events `{"kind": "event", ...,
+    "parent"}`; the final line is a `{"kind": "counters"}` snapshot of
+    every registered `CounterGroup`.  `repro.obs.report` and the tests
+    consume this form via `load_jsonl`.
   * **Chrome trace-event JSON** — `{"traceEvents": [...]}` with `ph:"X"`
-    complete events (ts/dur in microseconds), `ph:"i"` instants and
-    `ph:"C"` counter samples, loadable in Perfetto (ui.perfetto.dev) or
-    `chrome://tracing`.
+    complete events (ts/dur in microseconds) and `ph:"i"` instants,
+    loadable in Perfetto (ui.perfetto.dev) or `chrome://tracing`.
 
 Attribute values are sanitized with `_jsonable` (numpy scalars → Python
 numbers, unknown objects → `repr`), so instrumentation sites may attach
@@ -80,14 +79,10 @@ def to_jsonl_records(tracer: Tracer) -> List[dict]:
         "attrs": _attrs(s.attrs),
     }) for s in tracer.spans()]
     for e in tracer.events():
-        rec = {"kind": e["kind"], "name": e["name"],
-               "ts_us": tracer.us(e["ts"]), "thread": e["thread"],
-               "attrs": _attrs(e["attrs"])}
-        if e["kind"] == "event":
-            rec["parent"] = e["parent"]
-        else:
-            rec["value"] = _jsonable(e["value"])
-        rows.append((e["ts"], 1, rec))
+        rows.append((e["ts"], 1, {
+            "kind": "event", "name": e["name"],
+            "ts_us": tracer.us(e["ts"]), "thread": e["thread"],
+            "attrs": _attrs(e["attrs"]), "parent": e["parent"]}))
     rows.sort(key=lambda r: (r[0], r[1]))
     recs.extend(r[2] for r in rows)
     recs.append({"kind": "counters", "values": _jsonable(all_counters())})
@@ -122,21 +117,11 @@ def to_chrome_trace(tracer: Tracer, process_name: str = "repro") -> dict:
             "args": _attrs(s.attrs),
         })
     for e in tracer.events():
-        if e["kind"] == "gauge":
-            val = e["value"]
-            if not isinstance(val, (int, float)):
-                continue            # Chrome counter tracks are numeric-only
-            ev.append({
-                "ph": "C", "pid": 0, "tid": e["thread"],
-                "name": e["name"], "ts": tracer.us(e["ts"]),
-                "args": {"value": _jsonable(val)},
-            })
-        else:
-            ev.append({
-                "ph": "i", "s": "t", "pid": 0, "tid": e["thread"],
-                "name": e["name"], "cat": e["name"].split(".", 1)[0],
-                "ts": tracer.us(e["ts"]), "args": _attrs(e["attrs"]),
-            })
+        ev.append({
+            "ph": "i", "s": "t", "pid": 0, "tid": e["thread"],
+            "name": e["name"], "cat": e["name"].split(".", 1)[0],
+            "ts": tracer.us(e["ts"]), "args": _attrs(e["attrs"]),
+        })
     return {"traceEvents": ev, "displayTimeUnit": "ms",
             "otherData": {"counters": _jsonable(all_counters())}}
 

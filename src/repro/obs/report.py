@@ -15,8 +15,9 @@ prints up to three tables (plain text, or GitHub-flavoured markdown with
   * **pallas islands** — one row per rate island of the fused pallas
     executor (`exec.pallas.island` spans): rate, fused stage count, grid,
     carrier mix, stored-container mix with the boundary-buffer MB it
-    materializes and the MB saved vs a uniform int32 baseline, and time
-    aggregated over calls;
+    materializes and the MB saved vs a uniform int32 baseline, and the
+    host's dispatch time aggregated over calls (`dispatch_ms`: the span
+    wraps an asynchronous call, so it holds no device time);
   * **design search** — per-strategy evaluation rollup (`dse.evaluate`
     spans + cached hits) and the Pareto frontier as accepted during the
     search (`dse.accept` events): psnr / power / area / total bits.
@@ -139,9 +140,9 @@ def summarize(records: List[dict]) -> Dict[str, List[Dict[str, Any]]]:
             "carriers": a.get("carriers"),
             "containers": a.get("containers"),
             "out_mb": a.get("out_mb"), "saved_mb": a.get("saved_mb"),
-            "ms": 0.0, "calls": 0,
+            "dispatch_ms": 0.0, "calls": 0,
         })
-        row["ms"] += s["dur_us"] / 1e3
+        row["dispatch_ms"] += s["dur_us"] / 1e3
         row["calls"] += 1
     islands = sorted(isl.values(), key=lambda r: (r["island"] is None,
                                                   r["island"]))
@@ -210,7 +211,7 @@ def render(summary: Dict[str, List[Dict[str, Any]]],
         _table("pallas islands",
                ["island", "rate", "stages", "grid", "single_tile",
                 "carriers", "containers", "out_mb", "saved_mb",
-                "ms", "calls"],
+                "dispatch_ms", "calls"],
                summary.get("islands", []), markdown),
         _table("design search strategies",
                ["pipeline", "strategy", "evals", "cached", "ms",

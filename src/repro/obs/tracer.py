@@ -13,18 +13,24 @@ Three primitives:
     attaches attributes mid-flight; attributes land in both exporters.
   * **events** — `event("smt.budget_exhausted", stage=...)` is an instant
     marker attached to the current span.
-  * **counters / gauges** — `CounterGroup` is a *dict subclass* with a
+  * **counters** — `CounterGroup` is a *dict subclass* with a
     lock, `add()` and `reset()`: the three legacy module-global stat dicts
     (`analysis.driver.MEMO_STATS` / `DISK_CACHE_STATS`,
     `smt.solver.STATS`) are byte-compatible shims over it — existing
     `STATS["hits"]`-style reads keep working while mutation is now locked
-    and resettable.  `gauge(name, value)` samples a numeric time series.
+    and resettable.
 
 Tracing is **off by default and free when off**: the module-level `span`
-/ `event` / `gauge` helpers check one global and return a shared no-op
-object, so the instrumented hot paths cost a pointer compare per call.
-Enable with `enable()` / `tracing()`; export with `repro.obs.exporters`
-(JSONL + Chrome trace-event JSON, perfetto-loadable).
+/ `event` helpers check one global and return a shared no-op object, so
+the instrumented hot paths cost a pointer compare per call.  Enable with
+`enable()` / `tracing()`; export with `repro.obs.exporters` (JSONL +
+Chrome trace-event JSON, perfetto-loadable).
+
+**One clock with the device.**  While a tracer is active, every span
+also enters a `jax.profiler.TraceAnnotation` of its own name, so a
+running JAX profiler records it on its ``/host:`` plane beside the
+device's ``XLA Ops``.  JAX is looked up once, when the tracer is
+installed; without it (or with the profiler idle) spans are unchanged.
 """
 from __future__ import annotations
 
@@ -35,7 +41,7 @@ from typing import Any, Dict, List, Optional
 
 __all__ = [
     "CounterGroup", "Span", "Tracer", "active_tracer", "all_counters",
-    "disable", "enable", "event", "gauge", "is_enabled",
+    "disable", "enable", "event", "is_enabled",
     "runtime_ranges_enabled", "span", "tracing",
 ]
 
@@ -77,7 +83,7 @@ class CounterGroup(dict):
             return v
 
     def set(self, key: str, value):
-        """Locked gauge-style assignment."""
+        """Locked assignment."""
         with self._lock:
             super().__setitem__(key, value)
 
@@ -108,7 +114,7 @@ class Span:
     through `Tracer.span` / the module-level `span` helper."""
 
     __slots__ = ("tracer", "name", "attrs", "span_id", "parent_id",
-                 "t0", "t1", "thread_id")
+                 "t0", "t1", "thread_id", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, Any]):
         self.tracer = tracer
@@ -119,6 +125,7 @@ class Span:
         self.t0 = 0.0
         self.t1 = 0.0
         self.thread_id = 0
+        self._annotation = None
 
     def set(self, **attrs) -> "Span":
         """Attach/overwrite attributes (any time before export)."""
@@ -132,11 +139,16 @@ class Span:
         stack = tr._stack()
         self.parent_id = stack[-1].span_id if stack else None
         stack.append(self)
+        if tr.annotation is not None:
+            self._annotation = tr.annotation(self.name)
+            self._annotation.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         self.t1 = time.perf_counter()
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         if exc_type is not None:
             self.attrs.setdefault("error", exc_type.__name__)
         stack = self.tracer._stack()
@@ -167,15 +179,20 @@ _NULL = _NullSpan()
 
 
 class Tracer:
-    """Thread-safe collector of spans, instant events, and gauge samples.
+    """Thread-safe collector of spans and instant events.
 
     `runtime_ranges=True` opts the execution backends into per-stage
     observed-range / saturation / alpha-headroom telemetry
     (`repro.obs.runtime`); plain tracing never touches pixel data.
+    Each span also enters `annotation` (`jax.profiler.TraceAnnotation`,
+    None without JAX) of its name for its life.  A tracer is built only
+    by `enable()` / `tracing()`, so JAX is looked up only when tracing is
+    installed.
     """
 
     def __init__(self, runtime_ranges: bool = False):
         self.runtime_ranges = runtime_ranges
+        self.annotation = _profiler_annotation()
         self.t0 = time.perf_counter()
         self.wall_t0 = time.time()
         self._ids = itertools.count(1)     # .__next__ is atomic under the GIL
@@ -211,13 +228,6 @@ class Tracer:
         with self._lock:
             self._events.append(rec)
 
-    def gauge(self, name: str, value, **attrs) -> None:
-        rec = {"kind": "gauge", "name": name,
-               "ts": time.perf_counter(), "value": value,
-               "thread": threading.get_ident(), "attrs": attrs}
-        with self._lock:
-            self._events.append(rec)
-
     # -- queries (exporters + tests) ----------------------------------------
     def spans(self, name: Optional[str] = None) -> List[Span]:
         with self._lock:
@@ -243,6 +253,15 @@ class Tracer:
 # ---------------------------------------------------------------------------
 
 _ACTIVE: Optional[Tracer] = None
+
+
+def _profiler_annotation():
+    """`jax.profiler.TraceAnnotation`, or None where JAX is not installed."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
 
 
 def enable(runtime_ranges: bool = False) -> Tracer:
@@ -307,9 +326,3 @@ def event(name: str, **attrs) -> None:
     t = _ACTIVE
     if t is not None:
         t.event(name, **attrs)
-
-
-def gauge(name: str, value, **attrs) -> None:
-    t = _ACTIVE
-    if t is not None:
-        t.gauge(name, value, **attrs)

@@ -26,9 +26,15 @@ Design points (docs/serving.md):
   * **drain** — `close()` serves every queued request (final partial
     batch padded), then joins the worker; `submit` after close raises.
 
-Telemetry: each served batch is an `obs.span("serve.batch")`; the
-process-wide `SERVE_STATS` counter group tracks frames / batches /
-padded frames.
+Telemetry (`repro.obs`, docs/observability.md): `serve.submit` on the
+caller's thread (the submit-side quantize and enqueue); on the batcher
+thread `serve.collect` (waiting for the queue to fill a batch) and
+`serve.batch` per served batch, with children `serve.stack` (stacking
+and padding), the executor's `exec.*` spans and `serve.deliver`
+(resolving the futures).  While tracing is on, `serve.batch` carries its
+requests' submit times (`t_submit`, on the tracer's
+`time.perf_counter` clock).  The process-wide `SERVE_STATS` counter
+group tracks frames / batches / padded frames.
 """
 from __future__ import annotations
 
@@ -57,7 +63,7 @@ class _Request:
     def __init__(self, images: List[np.ndarray]):
         self.images = images
         self.future: Future = Future()
-        self.t_submit = time.monotonic()
+        self.t_submit = time.perf_counter()     # the tracer's clock
 
 
 class PipelineServer:
@@ -151,8 +157,9 @@ class PipelineServer:
         dict of (H, W) arrays); resolves to ``{output: (H', W') f64}``."""
         if self._closed:
             raise RuntimeError("PipelineServer is closed")
-        req = _Request(self._normalize(image))
-        self._q.put(req)
+        with obs.span("serve.submit"):
+            req = _Request(self._normalize(image))
+            self._q.put(req)
         return req.future
 
     def warmup(self, shapes: Iterable[Tuple[int, int]]) -> List[tuple]:
@@ -206,13 +213,16 @@ class PipelineServer:
         n = len(reqs)
         pad = self.batch_size - n
         with obs.span("serve.batch", pipeline=self.pipeline.name,
-                      backend=self.backend, size=n, padded=pad):
+                      backend=self.backend, size=n, padded=pad) as sp:
+            if obs.is_enabled():
+                sp.set(t_submit=[r.t_submit for r in reqs])
             try:
-                batch = {}
-                for slot, name in enumerate(self._input_names):
-                    frames = [r.images[slot] for r in reqs]
-                    frames += [np.zeros_like(frames[0])] * pad
-                    batch[name] = np.stack(frames)
+                with obs.span("serve.stack"):
+                    batch = {}
+                    for slot, name in enumerate(self._input_names):
+                        frames = [r.images[slot] for r in reqs]
+                        frames += [np.zeros_like(frames[0])] * pad
+                        batch[name] = np.stack(frames)
                 out = self._executor(batch)
                 key = (self.batch_size,) + tuple(
                     batch[self._input_names[0]].shape[1:])
@@ -221,15 +231,17 @@ class PipelineServer:
                 for r in reqs:
                     r.future.set_exception(e)
                 return
-        SERVE_STATS.add("frames", n)
-        SERVE_STATS.add("batches")
-        SERVE_STATS.add("padded", pad)
-        for b, r in enumerate(reqs):
-            r.future.set_result({k: v[b] for k, v in out.items()})
+            SERVE_STATS.add("frames", n)
+            SERVE_STATS.add("batches")
+            SERVE_STATS.add("padded", pad)
+            with obs.span("serve.deliver"):
+                for b, r in enumerate(reqs):
+                    r.future.set_result({k: v[b] for k, v in out.items()})
 
     def _loop(self) -> None:
         while True:
-            reqs, stop = self._collect()
+            with obs.span("serve.collect"):
+                reqs, stop = self._collect()
             if reqs:
                 self._serve_batch(reqs)
             if stop:

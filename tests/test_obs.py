@@ -74,7 +74,9 @@ def test_disabled_tracing_is_shared_noop():
     with s1 as sp:
         assert sp.set(k=2) is sp                # fully inert
     obs.event("nothing")                        # no-op, no error
-    obs.gauge("nothing", 1.0)
+    with obs.span("nothing") as sp:             # no tracer, nothing recorded
+        obs.event("nothing.inside", v=1.0)
+    assert sp is s1 and obs.active_tracer() is None
     assert obs.runtime.record_stage("x", np.zeros((2, 2))) is None
 
 
@@ -100,6 +102,56 @@ def test_span_thread_safety():
     outer_of = {s.attrs["idx"]: s.span_id for s in outers}
     for s in inners:
         assert s.parent_id == outer_of[s.attrs["idx"]]
+
+
+def test_spans_are_profiler_host_events(tmp_path):
+    """A served batch's spans land in a running JAX profiler's trace as
+    ``/host:`` events under their own names: one clock with the device."""
+    import glob
+    import os
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from repro.pipelines import workflows as W
+    from repro.serve import PipelineServer, serve_offline
+    pipe = usm.build()
+    alphas, signed = W.static_alphas(pipe)
+    frames = list(np.random.default_rng(5).integers(
+        0, 256, (4, 32, 32)).astype(np.uint8))
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", RuntimeWarning)
+        types = W.types_from_alpha(pipe, alphas, signed,
+                                   {n: 4 for n in pipe.stages})
+        with PipelineServer(pipe, types, dict(usm.DEFAULT_PARAMS),
+                            backend="lowered", batch_size=4) as srv:
+            srv.warmup([(32, 32)])
+            with obs.tracing() as tr:
+                jax.profiler.start_trace(str(tmp_path))
+                try:
+                    serve_offline(srv, frames)
+                finally:
+                    jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                      recursive=True)
+    host = {e.name for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events}
+    want = {"serve.submit", "serve.batch", "exec.lowered", "exec.h2d",
+            "exec.dispatch", "exec.device_wait", "exec.d2h"}
+    assert want <= host
+    assert want <= {s.name for s in tr.spans()}
+
+
+def test_tracing_without_jax_profiler(monkeypatch):
+    # repro.obs needs no JAX: with none to import, spans are plain spans
+    import sys
+    monkeypatch.setitem(sys.modules, "jax.profiler", None)
+    with obs.tracing() as tr:
+        with obs.span("plain"):
+            pass
+    assert tr.annotation is None
+    assert [s.name for s in tr.spans()] == ["plain"]
 
 
 def test_counter_group_semantics():
@@ -138,7 +190,7 @@ def _tiny_trace():
         with obs.span("a.outer", k=1):
             with obs.span("a.inner", iv=Interval(0.0, 1.0)):
                 obs.event("a.mark", note="hi")
-            obs.gauge("a.gauge", 2.5)
+            obs.event("a.sample", value=2.5)
     return tr
 
 
@@ -156,10 +208,11 @@ def test_jsonl_round_trip(tmp_path):
     assert inner["parent"] == spans["a.outer"]["id"]
     assert inner["dur_us"] >= 0 and inner["ts_us"] >= 0
     assert isinstance(inner["attrs"]["iv"], str)    # repr-sanitized Interval
-    ev, = [r for r in recs if r["kind"] == "event"]
+    ev, sample = [r for r in recs if r["kind"] == "event"]
     assert ev["name"] == "a.mark" and ev["parent"] == inner["id"]
-    gg, = [r for r in recs if r["kind"] == "gauge"]
-    assert gg["value"] == 2.5
+    assert sample["name"] == "a.sample"
+    assert sample["parent"] == spans["a.outer"]["id"]
+    assert sample["attrs"] == {"value": 2.5}
 
 
 def test_chrome_trace_schema(tmp_path):
@@ -172,14 +225,14 @@ def test_chrome_trace_schema(tmp_path):
     assert ev[0] == {"ph": "M", "pid": 0, "tid": 0, "name": "process_name",
                      "args": {"name": "repro-test"}}
     phs = {e["ph"] for e in ev}
-    assert phs <= {"M", "X", "i", "C"}
+    assert phs == {"M", "X", "i"}
     xs = [e for e in ev if e["ph"] == "X"]
     assert {e["name"] for e in xs} == {"a.outer", "a.inner"}
     for e in xs:                                # perfetto-required fields
         assert isinstance(e["ts"], float) and isinstance(e["dur"], float)
         assert e["cat"] == "a"
     assert any(e["ph"] == "i" and e["name"] == "a.mark" for e in ev)
-    assert any(e["ph"] == "C" and e["args"]["value"] == 2.5 for e in ev)
+    assert any(e["ph"] == "i" and e["args"] == {"value": 2.5} for e in ev)
     assert doc["otherData"]["counters"].keys() >= {"smt.solver"}
 
 
@@ -375,10 +428,12 @@ def test_pallas_island_spans_and_report_breakdown(tmp_path):
         with obs.tracing() as tr:
             run_fixed(pipe, img, plan, backend="pallas")
     outer, = tr.spans("exec.pallas")
+    dispatch, = tr.spans("exec.dispatch")
     isl = tr.spans("exec.pallas.island")
     assert outer.attrs["islands"] == len(isl) > 1
+    assert dispatch.parent_id == outer.span_id
     for s in isl:
-        assert s.parent_id == outer.span_id
+        assert s.parent_id == dispatch.span_id
         assert s.attrs["stages"] >= 1 and s.attrs["grid"] >= 1
         assert "/" in s.attrs["rate"] or s.attrs["rate"].isdigit()
         assert s.attrs["carriers"]                  # non-empty datapath census
@@ -390,6 +445,7 @@ def test_pallas_island_spans_and_report_breakdown(tmp_path):
     rows = summary["islands"]
     assert {r["island"] for r in rows} == {s.attrs["island"] for s in isl}
     for r in rows:
-        assert r["calls"] == 1 and r["ms"] >= 0
+        assert r["calls"] == 1 and r["dispatch_ms"] >= 0
     md = report.render(summary, markdown=True)
     assert "pallas islands" in md and "single_tile" in md
+    assert "dispatch_ms" in md

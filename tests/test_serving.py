@@ -12,6 +12,7 @@ Three contracts under test (docs/serving.md):
   * **PipelineServer** — fixed-batch padding, drain-on-close, and
     end-to-end oracle equality through the background batcher.
 """
+import contextlib
 import threading
 import warnings
 
@@ -372,3 +373,130 @@ def test_pipeline_server_zero_copy_uint8_ingestion():
                                           err_msg=f"uint8/{k}")
             np.testing.assert_array_equal(a[k], b[k],
                                           err_msg=f"uint8 vs f64/{k}")
+
+
+# ---------------------------------------------------------------------------
+# host-path spans of a served batch (docs/observability.md)
+# ---------------------------------------------------------------------------
+
+HOST_PATH = [
+    ("pallas", dus.build, {}, "exec.pallas",
+     ["exec.h2d", "exec.dispatch", "exec.device_wait", "exec.d2h",
+      "exec.dequant"]),
+    # the lowered program dequantizes on the device: no exec.dequant
+    ("lowered", usm.build, dict(usm.DEFAULT_PARAMS), "exec.lowered",
+     ["exec.h2d", "exec.dispatch", "exec.device_wait", "exec.d2h"]),
+]
+
+
+@pytest.mark.parametrize("backend,build,params,exec_span,steps", HOST_PATH,
+                         ids=[h[0] for h in HOST_PATH])
+def test_served_batch_spans_cover_the_host_path(backend, build, params,
+                                                 exec_span, steps):
+    from repro.serve import PipelineServer, serve_offline
+    pipe = build()
+    types = _types_for(pipe)
+    shape = (512, 512)          # large enough that span overhead is noise
+    frames = list(_batch(1, 8, shape, seed=31).astype(np.uint8))
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", RuntimeWarning)
+        with PipelineServer(pipe, types, params, backend=backend,
+                            batch_size=4) as srv:
+            srv.warmup([shape])
+            with obs.tracing() as tr:
+                outs = serve_offline(srv, frames)
+    ref = run_fixed(pipe, frames[5], types, params)
+    for k in outs[5]:
+        np.testing.assert_array_equal(np.asarray(ref[k]), outs[5][k])
+
+    spans = tr.spans()
+
+    def children(p):
+        return [s for s in spans if s.parent_id == p.span_id]
+
+    batches = tr.spans("serve.batch")
+    assert sum(b.attrs["size"] for b in batches) == 8
+    for b in batches:
+        assert [c.name for c in children(b)] == \
+            ["serve.stack", exec_span, "serve.deliver"]
+    parents = tr.spans(exec_span)
+    assert len(parents) == len(batches)
+    covered = 0.0
+    for p in parents:
+        kids = children(p)
+        assert [k.name for k in kids] == steps
+        covered += sum(k.t1 - k.t0 for k in kids)
+    assert covered >= 0.95 * sum(p.t1 - p.t0 for p in parents)
+
+    # submit on the caller's thread, collect and batch on the batcher's
+    submits = tr.spans("serve.submit")
+    assert len(submits) == 8
+    batcher = {b.thread_id for b in batches}
+    assert len(batcher) == 1
+    assert {s.thread_id for s in submits}.isdisjoint(batcher)
+    assert {c.thread_id for c in tr.spans("serve.collect")} == batcher
+    # each batch carries its requests' submit times, on the span clock
+    for b in batches:
+        ts = b.attrs["t_submit"]
+        assert len(ts) == b.attrs["size"] and max(ts) <= b.t0
+        for t in ts:
+            assert any(s.t0 <= t <= s.t1 for s in submits)
+
+
+@pytest.mark.parametrize("traced", [False, True], ids=["off", "traced"])
+def test_h2d_waits_for_the_inputs_to_land(monkeypatch, traced):
+    """The input copies are asynchronous: `exec.h2d` blocks on them, so
+    their transfer is not charged to `exec.device_wait`, traced or not."""
+    import jax
+    import jax.numpy as jnp
+    from repro.lowering import backends as B
+    waited = []
+    real = jax.block_until_ready
+
+    def spy(x):
+        tr = obs.active_tracer()
+        waited.append((tr._stack()[-1].name if tr else None, x))
+        return real(x)
+
+    monkeypatch.setattr(jax, "block_until_ready", spy)
+    args = {"a": jnp.arange(4)}
+    with (obs.tracing() if traced else contextlib.nullcontext()):
+        res = B.run_on_device(None, ["y"], lambda: args,
+                              lambda a: {"y": a["a"] + 1}, dequantize=False)
+    np.testing.assert_array_equal(res["y"], np.arange(1, 5))
+    assert len(waited) == 2 and waited[0][1] is args
+    assert set(waited[1][1]) == {"y"}
+    if traced:
+        assert [n for n, _ in waited] == ["exec.h2d", "exec.device_wait"]
+
+
+def test_untraced_serving_computes_no_island_attributes(monkeypatch):
+    """The island spans' attributes are computed when the island is built
+    (at warm-up), never per served batch, traced or not."""
+    from repro.lowering.islands import Island
+    from repro.serve import PipelineServer, serve_offline
+    pipe = dus.build()
+    types = _types_for(pipe)
+    shape = (47, 48)            # rate-inexact: several islands
+    frames = list(_batch(1, 4, shape, seed=41).astype(np.uint8))
+
+    def refuse(self, *a):
+        raise AssertionError("island attribute computed while serving")
+
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", RuntimeWarning)
+        with PipelineServer(pipe, types, {}, backend="pallas",
+                            batch_size=4) as srv:
+            srv.warmup([shape])
+            for attr in ("stored_mix", "carrier_mix", "boundary_bytes"):
+                monkeypatch.setattr(Island, attr, refuse)
+            plain = serve_offline(srv, frames)
+            with obs.tracing() as tr:
+                traced = serve_offline(srv, frames)
+    for a, b, f in zip(plain, traced, frames):
+        ref = run_fixed(pipe, f, types, {})
+        for k in a:
+            np.testing.assert_array_equal(np.asarray(ref[k]), a[k])
+            np.testing.assert_array_equal(a[k], b[k])
+    isl = tr.spans("exec.pallas.island")
+    assert len(isl) > 1 and all(s.attrs["containers"] for s in isl)

@@ -74,8 +74,9 @@ def test_fused_kernel_compiles_for_v5e(one_chip, shape):
     assert plan.fully_fused
     for isl in plan.islands:
         call = fused_pipeline(island_program(lp, isl),
-                              grid=isl.schedule.grid, interpret=False,
-                              batch=BATCH)
+                              grid=isl.schedule.grid,
+                              name=f"fused_band_island_{isl.idx}",
+                              interpret=False, batch=BATCH)
         compiled = call.lower(*_frame_args(
             lp, isl.inputs, (BATCH,) + shape, one_chip)).compile()
         assert "tpu_custom_call" in compiled.as_text()
