@@ -280,18 +280,26 @@ def dequant(ls: LoweredStage, tile):
 
 def dequant_host(ls: LoweredStage, tile) -> np.ndarray:
     """`dequant` in numpy on a fetched container array — the oracle's own
-    arithmetic, so device programs need no f64 at their outputs."""
+    arithmetic, so device programs need no f64 at their outputs.
+
+    One pass into one fresh f64 array: the ufunc widens each element as
+    `astype` does and the power-of-two scale is exact, so the result is
+    bit-identical to ``a.astype(float64) * 2**-beta`` without its
+    temporary.  Fresh per call: served results are views of it."""
     a = np.asarray(tile)
     if ls.store_float:
         return a
-    return a.astype(np.float64) * (2.0 ** -ls.t.beta)
+    return np.multiply(a, 2.0 ** -ls.t.beta, dtype=np.float64,
+                       out=np.empty(a.shape, np.float64))
 
 
 def run_on_device(lp: LoweredPipeline, outs: Sequence[str],
                   to_device: Callable[[], object],
-                  dispatch: Callable[[object], Dict[str, object]],
-                  dequantize: bool = True) -> Dict[str, np.ndarray]:
-    """The fused executors' host path around one device program.
+                  dispatch: Callable[[object], Dict[str, object]]
+                  ) -> Dict[str, np.ndarray]:
+    """Every executor's host path around one device program: outputs
+    leave the device in their stored containers and are widened to the
+    oracle's f64 on the host.
 
     Each step is a child span of the caller's ``exec.*`` span:
 
@@ -305,10 +313,9 @@ def run_on_device(lp: LoweredPipeline, outs: Sequence[str],
       * ``exec.device_wait`` — block until the outputs in `outs` exist.
         The first copy would block there anyway, so waiting first changes
         no ordering; it gives the device's time its own span;
-      * ``exec.d2h`` — one `np.asarray` copy per output;
-      * ``exec.dequant`` — with `dequantize`, `dequant_host` of each
-        stored container tile to the oracle's f64 values (backends that
-        dequantize on the device pass False and skip this span).
+      * ``exec.d2h`` — one `np.asarray` copy per output container;
+      * ``exec.dequant`` — `dequant_host` of each stored container tile
+        to the oracle's f64 values, one pass each.
     """
     import jax
     with obs.span("exec.h2d"):
@@ -319,8 +326,6 @@ def run_on_device(lp: LoweredPipeline, outs: Sequence[str],
         out = jax.block_until_ready({n: out[n] for n in outs})
     with obs.span("exec.d2h"):
         res = {n: np.asarray(out[n]) for n in outs}
-    if not dequantize:
-        return res
     with obs.span("exec.dequant"):
         return {n: dequant_host(lp.stages[n], a) for n, a in res.items()}
 
@@ -369,8 +374,11 @@ def compile_jnp(lp: LoweredPipeline,
 
     Integer linear stages run as int32/int64 multiply-accumulates; every
     other stage replays the oracle's f64 expression tree
-    (`dsl.exec.eval_expr`) on dequantized operands.  Output dict values
-    are the same float64 arrays `run_fixed(backend="numpy")` produces.
+    (`dsl.exec.eval_expr`) on dequantized operands.  The program returns
+    each output's stored tile (its container, or f64 where the stage is
+    float-stored); `run` widens them on the host (`run_on_device`), so
+    its dict values are the same float64 arrays
+    `run_fixed(backend="numpy")` produces.
 
     Images with a leading batch dimension — ``(B, H, W)`` instead of
     ``(H, W)`` — run as ONE `vmap`-batched program over the same fused
@@ -454,7 +462,7 @@ def compile_jnp(lp: LoweredPipeline,
                                         container=fused_store_dtype(ls))
             vals[name] = dequant(ls, tiles[name])
             shapes[name] = tuple(vals[name].shape)
-        return {k: vals[k] for k in outs}
+        return {k: tiles[k] for k in outs}
 
     jitted = jax.jit(forward)
     vjitted = jax.jit(jax.vmap(forward))
@@ -492,12 +500,11 @@ def compile_jnp(lp: LoweredPipeline,
                     f"{[a.shape for a in arrs]}")
 
             with jax.enable_x64(True):
-                # outputs are dequantized to f64 inside the program
                 res = run_on_device(
                     lp, outs,
                     lambda: tuple(to_dev(im, n)
                                   for im, n in zip(imgs, in_names)),
-                    dispatch, dequantize=False)
+                    dispatch)
         # read-only post-processing: never feeds back into the computation
         obs.runtime.record_env(res, lp, backend="jnp")
         return res

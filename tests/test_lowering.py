@@ -645,3 +645,88 @@ def test_F3_containers_and_prequantized_ingest_on_random_pipelines(pipe,
     for stage in pipe.topo_order():
         np.testing.assert_array_equal(np.asarray(oracle[stage]), env[stage],
                                       err_msg=stage)
+
+
+# ---------------------------------------------------------------------------
+# the host widening: every executor ships containers, `dequant_host`
+# widens them to the oracle's f64 in one pass
+# ---------------------------------------------------------------------------
+
+from types import SimpleNamespace
+
+
+@pytest.mark.parametrize("container", [np.uint8, np.int8, np.uint16,
+                                       np.int16, np.uint32, np.int32,
+                                       np.int64],
+                         ids=lambda d: np.dtype(d).name)
+def test_dequant_host_widens_every_container_bit_exact(container):
+    """One ufunc pass equals ``astype(float64) * 2**-beta`` bit for bit
+    at the container's extremes and zero, into a fresh float64 array."""
+    info = np.iinfo(container)
+    a = np.array([[info.min, 0, info.max], [info.max, info.min, 0]],
+                 dtype=container)
+    for beta in (0, 4, 13):
+        ls = SimpleNamespace(store_float=False,
+                             t=FixedPointType(alpha=8, beta=beta))
+        got = B.dequant_host(ls, a)
+        want = a.astype(np.float64) * 2.0 ** -beta
+        assert got.dtype == np.float64 and got.shape == a.shape
+        np.testing.assert_array_equal(got.view(np.int64),
+                                      want.view(np.int64), err_msg=beta)
+        assert not np.shares_memory(got, a)
+
+
+def test_dequant_host_passes_float_stored_tiles_through():
+    tile = _img((4, 5), seed=3) / 7.0
+    ls = SimpleNamespace(store_float=True, t=None)
+    assert B.dequant_host(ls, tile) is tile
+
+
+LOWERED_CONTAINERS = [("usm", usm.build, dict(usm.DEFAULT_PARAMS)),
+                      ("hcd", hcd.build, {})]
+
+
+@pytest.mark.parametrize("name,build,params", LOWERED_CONTAINERS,
+                         ids=[c[0] for c in LOWERED_CONTAINERS])
+def test_lowered_program_returns_stored_containers(name, build, params):
+    """The jitted `lowered` program returns each int-stored stage in its
+    in-program container (`fused_store_dtype`; inputs in `store_dtype`),
+    not f64; float-stored stages stay f64."""
+    import jax
+    pipe = build()
+    lp = lower(pipe, _types_for(pipe), params=params)
+    run = compile_backend(lp, "jnp", outputs=list(pipe.stages))
+    with jax.enable_x64(True):
+        out = jax.eval_shape(run.forward, *[
+            jax.ShapeDtypeStruct((24, 32), np.float64)
+            for _ in pipe.input_stages()])
+    ints = 0
+    for n, ls in lp.stages.items():
+        if ls.store_float:
+            want = np.float64
+        elif ls.stage.is_input:
+            want = B.store_dtype(ls)
+        else:
+            want = B.fused_store_dtype(ls)
+        assert out[n].dtype == np.dtype(want), n
+        ints += out[n].dtype.kind in "iu"
+    assert ints, f"{name}: no stage is int-stored"
+
+
+@pytest.mark.parametrize("batched", [False, True],
+                         ids=["unbatched", "batched"])
+@pytest.mark.parametrize("name,build,params", LOWERED_CONTAINERS,
+                         ids=[c[0] for c in LOWERED_CONTAINERS])
+def test_lowered_run_widens_containers_to_oracle_f64(name, build, params,
+                                                     batched):
+    pipe = build()
+    types = _types_for(pipe)
+    imgs = np.stack([_img((24, 32), seed=41 + b) for b in range(2)])
+    img = imgs if batched else imgs[0]
+    oracle = run_fixed(pipe, img, types, params)
+    env = run_fixed(pipe, img, types, params, backend="lowered")
+    assert sorted(env) == sorted(pipe.stages)
+    for stage in pipe.stages:
+        assert env[stage].dtype == np.float64, stage
+        np.testing.assert_array_equal(np.asarray(oracle[stage]), env[stage],
+                                      err_msg=f"{name}/{stage}")

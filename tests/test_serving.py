@@ -15,11 +15,13 @@ Three contracts under test (docs/serving.md):
 import contextlib
 import threading
 import warnings
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
 
 from repro import obs
+from repro.core.fixedpoint import FixedPointType
 from repro.core.interval import Interval
 from repro.core.range_analysis import StageRange
 from repro.analysis import run_plan
@@ -383,9 +385,9 @@ HOST_PATH = [
     ("pallas", dus.build, {}, "exec.pallas",
      ["exec.h2d", "exec.dispatch", "exec.device_wait", "exec.d2h",
       "exec.dequant"]),
-    # the lowered program dequantizes on the device: no exec.dequant
     ("lowered", usm.build, dict(usm.DEFAULT_PARAMS), "exec.lowered",
-     ["exec.h2d", "exec.dispatch", "exec.device_wait", "exec.d2h"]),
+     ["exec.h2d", "exec.dispatch", "exec.device_wait", "exec.d2h",
+      "exec.dequant"]),
 ]
 
 
@@ -460,10 +462,12 @@ def test_h2d_waits_for_the_inputs_to_land(monkeypatch, traced):
 
     monkeypatch.setattr(jax, "block_until_ready", spy)
     args = {"a": jnp.arange(4)}
+    lp = SimpleNamespace(stages={"y": SimpleNamespace(
+        store_float=False, t=FixedPointType(alpha=4, beta=1))})
     with (obs.tracing() if traced else contextlib.nullcontext()):
-        res = B.run_on_device(None, ["y"], lambda: args,
-                              lambda a: {"y": a["a"] + 1}, dequantize=False)
-    np.testing.assert_array_equal(res["y"], np.arange(1, 5))
+        res = B.run_on_device(lp, ["y"], lambda: args,
+                              lambda a: {"y": a["a"] + 1})
+    np.testing.assert_array_equal(res["y"], np.arange(1, 5) / 2)
     assert len(waited) == 2 and waited[0][1] is args
     assert set(waited[1][1]) == {"y"}
     if traced:
