@@ -11,12 +11,10 @@ Phases with no option:
   * kernel:  dus_ext through `PipelineServer(backend="pallas")`, batch 4,
              at 1920x1080 and 3840x2160 — the fused kernel runs natively
              (never in interpret mode);
-  * lowered: usm through `PipelineServer(backend="lowered")`, batch 4,
-             at 1920x1080 (its exact datapath keeps f64 stages, which the
-             fused kernel does not take).  hcd is left out: XLA:TPU
-             compiles its f64 datapath, but the f64 replay of its
-             `harris` stage does not reproduce the numpy oracle bit for
-             bit (the line printed before the phases says so).
+  * lowered: usm and hcd through `PipelineServer(backend="lowered")`,
+             batch 4, at 1920x1080: usm's exact datapath keeps f64
+             stages, and hcd's all-integer program has 37- and 38-bit
+             stages (int64), which the fused kernel does not take.
 
 With ``--chips 4`` only the sharded phase runs: dus_ext through
 `PipelineServer(backend="sharded")` at 3840x2160 on a 4-device band
@@ -61,13 +59,15 @@ def _frames(rng, n, shape):
 
 
 def _check(label, outs, frames, pipe, types, params):
-    """Every served frame must equal the numpy oracle, stage by stage."""
+    """Every served frame must equal the numpy oracle, in every stage
+    the server returned (the declared outputs among them)."""
     import numpy as np
     from repro.dsl.exec import run_fixed
     for i, (out, img) in enumerate(zip(outs, frames)):
         ref = run_fixed(pipe, img, types, params)
-        for k in pipe.outputs:
-            if not np.array_equal(np.asarray(ref[k]), out[k]):
+        for k in set(pipe.outputs) | set(out):
+            if k not in out or not np.array_equal(np.asarray(ref[k]),
+                                                  out[k]):
                 raise AssertionError(
                     f"{label}: frame {i} stage {k!r} differs from the "
                     f"oracle")
@@ -101,11 +101,7 @@ def _serve(label, pipe, params, backend, shape, frames):
 
 def one_chip(seed: int) -> None:
     import numpy as np
-    from repro.pipelines import dus, usm
-    print("phase=lowered/hcd skipped: XLA:TPU's f64 replay of hcd's "
-          "'harris' stage (det - 0.04*trace^2) differs from the numpy "
-          "oracle; hcd waits for a fixed-point finish without f64",
-          flush=True)
+    from repro.pipelines import dus, hcd, usm
     rng = np.random.default_rng(seed)
     for size in ("1080p", "4k"):
         shape = SIZES[size]
@@ -114,6 +110,8 @@ def one_chip(seed: int) -> None:
     shape = SIZES["1080p"]
     _serve("lowered/usm", usm.build(), dict(usm.DEFAULT_PARAMS), "lowered",
            shape, _frames(rng, FRAMES, shape))
+    _serve("lowered/hcd", hcd.build(), {}, "lowered", shape,
+           _frames(rng, FRAMES, shape))
 
 
 def four_chips(seed: int) -> None:

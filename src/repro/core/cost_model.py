@@ -152,21 +152,61 @@ def _intlinear_cost(dp: Dict, w_in_max: float, w_out: int,
     operand bits), the accumulate chain runs at the *carrier* register
     width — 32 for int32 and each half of an int32pair, 64 for int64 —
     and an int32pair pays one widening 64-bit combine adder.  The finish
-    is a round+shift at carrier width when dyadic, else one f64 multiply.
+    is a round+shift at carrier width when dyadic; a rational finish adds
+    a constant division (one reciprocal multiply) and the tie compare;
+    an f64 finish is one f64 multiply.
     """
     A = CARRIER_BITS[dp["carrier"]]
     dsp = dp.get("wbits", 8 * dp["taps"]) * w_in_max / 8.0
     adders = dp["taps"] * A
     if dp["carrier"] == "int32pair":
         adders += 64                           # the widening combine
-    if dp.get("dyadic", True):
+    finish = dp.get("finish", "shift" if dp.get("dyadic", True) else "f64")
+    if finish == "shift":
         finish_ops, finish_dsp = float(A), 0.0  # round add + shift
+    elif finish == "rational":
+        finish_ops, finish_dsp = 2.0 * A, A * A / 8.0
     else:
         finish_ops, finish_dsp = 0.0, F64_MANTISSA * F64_MANTISSA / 8.0
     # (the output register + saturate clamp are charged by stage_cost's
     # common tail, like every other datapath)
     bit_ops = dsp + adders + finish_ops + finish_dsp
     return bit_ops, adders + finish_ops, dsp + finish_dsp
+
+
+def _intpoly_cost(e: Expr, dp: Dict) -> Tuple[float, float, float]:
+    """(bit_ops, lut_bits, dsp_bits) of a lowered integer polynomial.
+
+    Every operator runs at the *carrier* width A, as `_intlinear_cost`
+    prices the MAC: adds and constant multiplies are A-bit adders, a
+    product of two stages (or a `Pow` step) an A x A multiplier.  The
+    finish is a round+shift, or for a rational finish a constant division
+    (one reciprocal multiply) and the tie compare."""
+    A = CARRIER_BITS[dp["carrier"]]
+    adders = mults = 0
+
+    def go(n: Expr) -> None:
+        nonlocal adders, mults
+        if isinstance(n, Pow):
+            go(n.base)
+            mults += n.n - 1
+        elif isinstance(n, BinOp):
+            go(n.left)
+            go(n.right)
+            if n.op in "+-":
+                adders += 1
+            elif isinstance(n.left, Const) or isinstance(n.right, Const):
+                c = n.left if isinstance(n.left, Const) else n.right
+                adders += abs(c.value) not in (0.0, 1.0)
+            else:
+                mults += 1
+
+    go(e)
+    lut = adders * A + (2.0 * A if dp.get("finish") == "rational" else A)
+    dsp = mults * A * A / 8.0
+    if dp.get("finish") == "rational":
+        dsp += A * A / 8.0
+    return lut + dsp, lut, dsp
 
 
 def stage_cost(pipeline: Pipeline, name: str,
@@ -199,6 +239,8 @@ def stage_cost(pipeline: Pipeline, name: str,
     if datapath is not None and datapath.get("kind") == "intlinear":
         bit_ops, lut, dsp = _intlinear_cost(
             datapath, max(w_in.values(), default=8.0), w_out)
+    elif datapath is not None and datapath.get("kind") == "intpoly":
+        bit_ops, lut, dsp = _intpoly_cost(st.expr, datapath)
     elif datapath is not None and datapath.get("kind") == "expr":
         mant = FLOAT_MANTISSA if datapath.get("dtype") == "f32" \
             else F64_MANTISSA
@@ -251,8 +293,12 @@ def lowered_datapaths(lp) -> Dict[str, Dict]:
     narrow re-election (`lower(..., datapath="narrow")`) changes and the
     type-map-only model cannot see:
 
-      intlinear: {"kind", "carrier", "taps", "wbits", "dyadic"}
+      intlinear: {"kind", "carrier", "taps", "wbits", "dyadic", "finish"}
+      intpoly:   {"kind", "carrier", "finish"}
       expr:      {"kind", "dtype"}           # "f64" | "f32"
+
+    ``finish`` is "shift" (round-half-even shift), "rational" (the
+    proved integer finish of a non-dyadic scale or constant) or "f64".
     """
     out: Dict[str, Dict] = {}
     for n, ls in lp.stages.items():
@@ -263,7 +309,10 @@ def lowered_datapaths(lp) -> Dict[str, Dict]:
                       "taps": len(ls.int_taps),
                       "wbits": sum(max(abs(tp.W).bit_length(), 1)
                                    for tp in ls.int_taps),
-                      "dyadic": ls.dyadic}
+                      "dyadic": ls.dyadic, "finish": ls.finish}
+        elif ls.kind == "intpoly":
+            out[n] = {"kind": "intpoly", "carrier": ls.carrier,
+                      "finish": ls.finish}
         elif ls.kind == "expr":
             out[n] = {"kind": "expr", "dtype": ls.expr_dtype}
     return out

@@ -5,8 +5,8 @@ A backend compiles a `LoweredPipeline` into an executor
 
   * ``interp``  — the per-stage `dsl.exec.run_fixed` oracle (numpy f64),
                   kept bit-identical by definition;
-  * ``jnp``     — one fused jit program: integer multiply-accumulate
-                  datapaths for provably-exact linear stages, f64 replay
+  * ``jnp``     — one fused jit program: integer datapaths for
+                  provably-exact linear and polynomial stages, f64 replay
                   for the rest, all under an x64 scope.  Bit-identical to
                   the oracle (see `repro.lowering.ir` for the argument);
   * ``pallas``  — the fused line-buffer kernel (`pallas_backend`).
@@ -219,6 +219,31 @@ def ingest_host(lp: LoweredPipeline, names: Sequence[str], img_of: Dict):
     return buffers, shape
 
 
+def rational_round(x, a: int, b: int, base=None):
+    """The rational finish `ir._prove_rational` elects: ``base + h`` with
+    h = floor((2a*x + b) / 2b), and on a tie (remainder 0) the even of
+    ``base + h - 1`` and ``base + h``.  Integer ops only; the proof
+    bounds every intermediate inside `x`'s carrier."""
+    y = x * (2 * a) + b
+    h = y // (2 * b)
+    tie = (y - h * (2 * b)) == 0
+    q = h if base is None else base + h
+    return q - (tie & ((q & 1) == 1)).astype(q.dtype)
+
+
+def saturate(ls: LoweredStage, q, rows, cols, container=None):
+    """Clip a scaled-int tile to the stage's bounds (union or per
+    residue) and store it in its container (`container` overrides it;
+    it must hold the clipped range)."""
+    import jax.numpy as jnp
+    if ls.phase is not None:
+        qmin, qmax = residue_bounds(ls.phase, ls.t, rows, cols)
+        q = jnp.clip(q, qmin, qmax)
+    else:
+        q = jnp.clip(q, ls.t.int_min, ls.t.int_max)
+    return q.astype(container if container is not None else store_dtype(ls))
+
+
 def finish_intlinear(ls: LoweredStage, acc, rows, cols, container=None):
     """Accumulator -> saturated scaled-int tile (union + per-residue).
 
@@ -229,14 +254,40 @@ def finish_intlinear(ls: LoweredStage, acc, rows, cols, container=None):
     import jax.numpy as jnp
     if ls.dyadic:
         q = rhe_shift(acc * ls.sm if ls.sm != 1 else acc, ls.t_shift)
+    elif ls.rat is not None:
+        q = rational_round(acc, *ls.rat)
     else:
         q = jnp.rint(acc.astype(jnp.float64) * ls.cscale)
-    if ls.phase is not None:
-        qmin, qmax = residue_bounds(ls.phase, ls.t, rows, cols)
-        q = jnp.clip(q, qmin, qmax)
+    return saturate(ls, q, rows, cols, container)
+
+
+def eval_intpoly(ls: LoweredStage, tap, beta_of, rows, cols,
+                 container=None):
+    """An ``intpoly`` stage on scaled integers in its carrier.
+
+    ``tap(stage, dy, dx)`` yields the stored input tile at the output's
+    positions; ``beta_of(stage)`` an input's grid.  The exact part is
+    finished by the round-half-even shift, or with a non-dyadic root
+    constant shifted onto the output grid and added in the rational
+    finish of the enumerated term (`ir._plan_intpoly` holds the proof)."""
+    from repro.lowering.ir import int_eval
+    cdt = carrier_dtype(ls.carrier)
+
+    def leaf(r):
+        return tap(r.stage, r.dy, r.dx).astype(cdt)
+
+    beta = ls.t.beta
+    a = None
+    if ls.poly_exact is not None:
+        a, ea = int_eval(ls.poly_exact, leaf, beta_of)
+    if ls.rat is None:
+        q = rhe_shift(a, ea - beta)
     else:
-        q = jnp.clip(q, ls.t.int_min, ls.t.int_max)
-    return q.astype(container if container is not None else store_dtype(ls))
+        if a is not None and beta > ea:
+            a = a << (beta - ea)
+        x, _ = int_eval(ls.rat_term, leaf, beta_of)
+        q = rational_round(x, *ls.rat, base=a)
+    return saturate(ls, q, rows, cols, container)
 
 
 def snap_expr(ls: LoweredStage, raw, rows, cols, container=None):
@@ -261,13 +312,8 @@ def snap_expr(ls: LoweredStage, raw, rows, cols, container=None):
         return out
     if ls.store_float:                  # wide type: keep the oracle floats
         return snap_float(raw, t, jnp)
-    q = jnp.rint(raw * (2.0 ** t.beta))
-    if ls.phase is not None:
-        qmin, qmax = residue_bounds(ls.phase, t, rows, cols)
-        q = jnp.clip(q, qmin, qmax)
-    else:
-        q = jnp.clip(q, t.int_min, t.int_max)
-    return q.astype(container if container is not None else store_dtype(ls))
+    return saturate(ls, jnp.rint(raw * (2.0 ** t.beta)), rows, cols,
+                    container)
 
 
 def dequant(ls: LoweredStage, tile):
@@ -372,12 +418,14 @@ def compile_jnp(lp: LoweredPipeline,
                 outputs: Optional[Sequence[str]] = None) -> Executor:
     """One jitted x64 program with the oracle's padded-grid geometry.
 
-    Integer linear stages run as int32/int64 multiply-accumulates; every
-    other stage replays the oracle's f64 expression tree
-    (`dsl.exec.eval_expr`) on dequantized operands.  The program returns
-    each output's stored tile (its container, or f64 where the stage is
-    float-stored); `run` widens them on the host (`run_on_device`), so
-    its dict values are the same float64 arrays
+    Integer linear stages run as int32/int64 multiply-accumulates and
+    integer polynomial stages (``intpoly``) on the same scaled integers;
+    every other stage replays the oracle's f64 expression tree
+    (`dsl.exec.eval_expr`) on dequantized operands, which are made only
+    for those stages.  The program returns each output's stored tile
+    (its container, or f64 where the stage is float-stored); `run`
+    widens them on the host (`run_on_device`), so its dict values are
+    the same float64 arrays
     `run_fixed(backend="numpy")` produces.
 
     Images with a leading batch dimension — ``(B, H, W)`` instead of
@@ -397,10 +445,18 @@ def compile_jnp(lp: LoweredPipeline,
 
     def forward(*images):
         tiles: Dict[str, object] = {}      # stored tiles (int grid or f64)
-        vals: Dict[str, object] = {}       # f64 env values (lazy-ish)
+        vals: Dict[str, object] = {}       # f64 env values, made on demand
         shapes: Dict[str, tuple] = {}
         input_names = lp.pipeline.input_stages()
         img_of = dict(zip(input_names, images))
+
+        def val(i):
+            # only an f64 expr replay reads dequantized values, so a
+            # program with none of them traces no f64 op at all
+            if i not in vals:
+                vals[i] = dequant(lp.stages[i], tiles[i])
+            return vals[i]
+
         for name in order:
             ls = lp.stages[name]
             st = ls.stage
@@ -409,7 +465,6 @@ def compile_jnp(lp: LoweredPipeline,
                 # trace-time branch: a container-dtype input arrives
                 # pre-quantized and is the stored tile zero-copy
                 tiles[name] = ingest_input(x, ls, jnp)
-                vals[name] = dequant(ls, tiles[name])
                 shapes[name] = x.shape
                 continue
             in_shape = shapes[st.inputs[0]]
@@ -438,13 +493,28 @@ def compile_jnp(lp: LoweredPipeline,
                                      jnp.arange(acc.shape[1])[None, :],
                                      container=fused_store_dtype(ls))
                 tiles[name] = q
+            elif ls.kind == "intpoly":
+                padded = _pad_inputs({i: tiles[i] for i in st.inputs}, st,
+                                     jnp)
+                sy, sx = st.stride
+
+                def tap(stage, dy, dx, padded=padded, H=H, W=W, hy=hy,
+                        hx=hx, sy=sy, sx=sx):
+                    return padded[stage][hy + dy: hy + dy + H: sy,
+                                         hx + dx: hx + dx + W: sx]
+
+                tiles[name] = eval_intpoly(
+                    ls, tap, lambda i: lp.stages[i].t.beta,
+                    jnp.arange(_ceil_div(H, sy))[:, None],
+                    jnp.arange(_ceil_div(W, sx))[None, :],
+                    container=fused_store_dtype(ls))
             else:
                 if ls.expr_dtype == "f32":
                     padded = _pad_inputs(
                         {i: dequant_f32(lp.stages[i], tiles[i])
                          for i in st.inputs}, st, jnp)
                 else:
-                    padded = _pad_inputs({i: vals[i] for i in st.inputs},
+                    padded = _pad_inputs({i: val(i) for i in st.inputs},
                                          st, jnp)
 
                 def ref(stage, dy, dx, padded=padded, H=H, W=W,
@@ -460,19 +530,20 @@ def compile_jnp(lp: LoweredPipeline,
                                         jnp.arange(raw.shape[0])[:, None],
                                         jnp.arange(raw.shape[1])[None, :],
                                         container=fused_store_dtype(ls))
-            vals[name] = dequant(ls, tiles[name])
-            shapes[name] = tuple(vals[name].shape)
+            shapes[name] = tuple(tiles[name].shape)
         return {k: tiles[k] for k in outs}
 
     jitted = jax.jit(forward)
     vjitted = jax.jit(jax.vmap(forward))
+    census = lp.census(order)
 
     def run(image, params_override=None):
         if params_override is not None and dict(params_override) != params:
             raise ValueError("params are baked at compile time; re-lower "
                              "with the new params")
         with obs.span("exec.lowered", backend="jnp",
-                      pipeline=lp.pipeline.name, outputs=len(outs)) as sp:
+                      pipeline=lp.pipeline.name, outputs=len(outs),
+                      **census) as sp:
             imgs, in_names = normalize_images(lp, image)
 
             # container-dtype frames ship narrow (zero-copy ingest);

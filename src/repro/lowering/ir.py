@@ -28,10 +28,28 @@ The finishing step after an integer accumulation:
 
   * dyadic s = sm/2^se  ->  q_out = round_half_even(acc * sm, t) with
     t = se + w_beta + bmax - beta_out (pure integer datapath);
-  * otherwise  q_out = rint(f64(acc) * cscale) with cscale =
-    s * 2^(beta_out - w_beta - bmax), exact because scaling a double by a
-    power of two is lossless — one IEEE multiply, the same one the oracle
-    issues.
+  * otherwise the oracle computes q_out = rint(fl(acc * cscale)) with
+    cscale = s * 2^(beta_out - w_beta - bmax) (scaling a double by a power
+    of two is lossless).  `_prove_rational` elects an integer *rational
+    finish* ``(a, b)``: h = floor((2a*acc + b) / 2b), a remainder of 0 is
+    a tie and goes to even.  It is checked against the oracle's own IEEE
+    multiply and `rint` for every accumulator value in the proved range,
+    so the integers reproduce both roundings, ties included.  Where no
+    such proof exists the stage keeps that one f64 multiply, and says
+    why in `proof`.
+
+An ``intpoly`` stage is a polynomial of on-grid stages: +, -, *, `Pow`
+and dyadic constants.  Its oracle f64 tree is exact while every node's
+scaled magnitude stays below 2^53 (the `_plan_intpoly` walk), so the
+same tree evaluated on the scaled integers, finished by the
+round-half-even shift, is bit-equal.  One non-dyadic constant is allowed
+at the root, as ``A ± c * X`` or ``c * X``: the oracle then rounds
+``fl(c * X)`` and ``fl(A ± .)`` before its `rint`.  X must be a function
+of one input value (enumerable), and the same rational finish is proved
+for it: every X value is enumerated, and the inner ``fl(A ± .)`` can
+only matter where ``±c*X*2^beta`` lies within half an ulp of |A ± .|'s
+largest value from a half-integer, which the proof rules out (or finds
+exact ties, which go to even on the integers as in the oracle).
 
 **Narrow datapath re-election** (`lower(..., datapath="narrow")`) is the
 real-hardware mode: the exact-mode election above happily hands out
@@ -58,12 +76,14 @@ the plan's provenance:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from fractions import Fraction
 from typing import Dict, List, Optional, Sequence, Tuple
 
+from repro import obs
 from repro.core.fixedpoint import FixedPointType
-from repro.core.graph import BinOp, Const, Expr, Pipeline, Ref, Stage
+from repro.core.graph import BinOp, Const, Expr, Pipeline, Pow, Ref, Stage
 
 Residue = Tuple[int, int]
 
@@ -181,7 +201,7 @@ class PhaseSnap:
 @dataclasses.dataclass
 class LoweredStage:
     name: str
-    kind: str                        # "input" | "intlinear" | "expr"
+    kind: str                        # "input"|"intlinear"|"intpoly"|"expr"
     stage: Stage                     # original IR node (expr/stride/upsample)
     t: Optional[FixedPointType]      # union-column output type (None = float)
     halo: Tuple[int, int]            # per-axis (hy, hx)
@@ -206,6 +226,49 @@ class LoweredStage:
     # narrow-mode election record ("" in exact mode): the chosen datapath,
     # with the proof obligation that blocked anything narrower
     election: str = ""
+    # -- exact integer finish and intpoly datapath -------------------------
+    # rational finish (a, b): h = floor((2a*x + b) / 2b), ties to even
+    # (intlinear: x = acc; intpoly: x = the value of `rat_term`)
+    rat: Optional[Tuple[int, int]] = None
+    poly_exact: Optional[Expr] = None    # intpoly: the exact part A (or None)
+    rat_term: Optional[Expr] = None      # intpoly: X of A ± c*X (or None)
+    # the proof behind an integer finish or intpoly election, or the reason
+    # a stage stays on f64; recorded in the plan's provenance
+    proof: str = ""
+
+    @property
+    def finish(self) -> str:
+        """How the stage lands on its output grid: "shift" (round-half-
+        even shift), "rational" (exact integer finish of a non-dyadic
+        scale), "f64" (one IEEE multiply) or "float" (an expr snap)."""
+        if self.kind == "expr":
+            return "float"
+        if self.rat is not None:
+            return "rational"
+        if self.kind == "intlinear" and not self.dyadic:
+            return "f64"
+        return "shift"
+
+    @property
+    def datapath_carrier(self) -> str:
+        """The register the stage's arithmetic runs in."""
+        if self.kind in ("intlinear", "intpoly"):
+            return self.carrier
+        return self.expr_dtype
+
+    @property
+    def uses_f64(self) -> bool:
+        """True when the device program touches f64 for this stage."""
+        if self.kind == "input":
+            return self.store_float
+        return (self.store_float or self.finish == "f64"
+                or self.datapath_carrier == "f64")
+
+    @property
+    def wide(self) -> bool:
+        """True for an int64 or int32-pair carrier."""
+        return (self.kind in ("intlinear", "intpoly")
+                and self.carrier in ("int64", "int32pair"))
 
 
 @dataclasses.dataclass
@@ -225,10 +288,24 @@ class LoweredPipeline:
     def kinds(self) -> Dict[str, str]:
         return {n: s.kind for n, s in self.stages.items()}
 
+    def census(self, names: Sequence[str]) -> Dict[str, int]:
+        """``f64_stages`` and ``wide_stages`` among ``names`` (a program's
+        stages): the attributes every executor span carries."""
+        return _census(self.stages[n] for n in names)
+
+
+def _census(stages) -> Dict[str, int]:
+    sts = list(stages)
+    return dict(f64_stages=sum(s.uses_f64 for s in sts),
+                wide_stages=sum(s.wide for s in sts))
+
 
 # ---------------------------------------------------------------------------
 # datapath planning
 # ---------------------------------------------------------------------------
+
+# stages of each `lower()` by "<kind>.<carrier>.<finish>" (and "lowerings")
+DATAPATH_STATS = obs.CounterGroup("lowering.datapath", lowerings=0)
 
 F64_EXACT = 1 << 53      # integer sums below this are exact IEEE doubles
 F32_EXACT = 1 << 24      # scaled magnitudes below this are exact IEEE singles
@@ -457,13 +534,30 @@ def _plan_intlinear(st: Stage, taps: Tuple[Tap, ...], scale: float,
                     dyadic=True, cscale=1.0, acc_bound=bound)
         gate = fin       # the finishing multiply/shift runs in-carrier
     else:
-        # non-dyadic scale: one f64 multiply finishes the stage, bit-equal
-        # to the oracle's fl(scale * sum) (power-of-two rescale is
-        # lossless); the carrier only has to hold the raw accumulator
+        # non-dyadic scale: the oracle's fl(scale * sum), then rint.  An
+        # integer rational finish where `_prove_rational` reproduces both
+        # roundings over every accumulator value; else one f64 multiply,
+        # bit-equal to the oracle's (power-of-two rescale is lossless)
         cscale = scale * 2.0 ** (t_out.beta - w_beta - bmax)
         plan = dict(int_taps=tuple(int_taps), sm=1, t_shift=0, dyadic=False,
                     cscale=cscale, acc_bound=bound)
         gate = bound
+        if 2 * bound + 1 > ENUM_CAP:
+            plan["proof"] = (f"f64 finish kept: accumulator range "
+                             f"2^{(2 * bound + 1).bit_length()} exceeds the "
+                             f"enumeration cap 2^{ENUM_CAP.bit_length() - 1}")
+        else:
+            rat = _prove_rational(scale, 1, t_out.beta, w_beta + bmax,
+                                  -bound, bound, None)
+            if isinstance(rat, str):
+                plan["proof"] = f"f64 finish kept: {rat}"
+            else:
+                (a, b), ties = rat
+                plan.update(rat=(a, b), proof=(
+                    f"rational finish {a}/{b}: rint(fl(acc*{scale!r})) "
+                    f"reproduced for every acc in [{-bound}, {bound}] "
+                    f"({ties} ties to even)"))
+                gate = max(bound, 2 * abs(a) * bound + b)
     if gate < INT32_BUDGET:
         plan.update(carrier="int32", acc_split=0,
                     election="int32" if narrow else "")
@@ -494,6 +588,275 @@ def _plan_intlinear(st: Stage, taps: Tuple[Tap, ...], scale: float,
     return plan
 
 
+ENUM_CAP = 1 << 22        # grid values a rational-finish proof enumerates
+RAT_MAX_DEN = 1 << 20     # largest denominator of a rational finish
+INT64_BUDGET = 1 << 62
+
+
+def _convergents(r: Fraction, max_den: int):
+    """Continued-fraction convergents ``(a, b)`` of ``r``, b <= max_den."""
+    sign = -1 if r < 0 else 1
+    x = abs(r)
+    h0, h1, k0, k1 = 0, 1, 1, 0
+    while True:
+        q = x.numerator // x.denominator
+        h0, h1, k0, k1 = h1, q * h1 + h0, k1, q * k1 + k0
+        if k1 > max_den:
+            return
+        yield sign * h1, k1
+        x -= q
+        if x == 0:
+            return
+        x = 1 / x
+
+
+@functools.lru_cache(maxsize=64)
+def _prove_rational(c: float, s: int, beta: int, e: int, lo: int, hi: int,
+                    abound: Optional[int], xs_key=None):
+    """Exact integer finish of the oracle's ``rint(2^beta * fl(A + s *
+    fl(c * X)))`` (``A`` absent when ``abound`` is None), or the reason
+    there is none.
+
+    X takes the values ``x * 2^-e`` for the integers x in ``[lo, hi]``
+    (or, with ``xs_key = (expr, input beta)``, the scaled values of the
+    univariate polynomial `expr` over that range of its one input).  A
+    is on the output grid, ``|A * 2^beta| <= abound``.  Every x is
+    enumerated, and numpy's IEEE ``c * X`` (the oracle's own op) gives
+    sY = s * 2^beta * fl(c * X) exactly.  With N = A*2^beta + floor(sY)
+    the oracle returns N + [sY - floor(sY) > 1/2], or the even of N and
+    N + 1 on an exact tie, provided the inner fl(A + .) never lands on a
+    half-integer that sY does not: its half-ulp at the largest
+    |A*2^beta + sY| must be below every non-tie distance from a
+    half-integer (checked with a factor-2 margin against the rounding of
+    that distance).  The device computes
+    h = floor((2a*x + b) / 2b), a tie where the remainder is 0; the
+    proof is that h and the tie flags equal the oracle's for every x.
+    Returns ``((a, b), ties)`` or a reason string."""
+    import numpy as np
+    xs = np.arange(lo, hi + 1, dtype=np.int64)
+    if xs_key is not None:
+        expr, in_beta = xs_key
+        xs, ex = int_eval(expr, lambda r: xs, lambda n: in_beta)
+        assert ex == e, (ex, e)
+    if not 2.0 ** -60 <= abs(c) <= 2.0 ** 60:
+        return f"constant {c!r} outside the normal range"
+    sy = s * (c * (xs.astype(np.float64) * 2.0 ** -e)) * 2.0 ** beta
+    top = float(np.max(np.abs(sy)))
+    if top >= 2.0 ** 50:
+        return "the scaled term leaves the exact-integer range"
+    k = np.floor(sy)
+    half_pt = k + 0.5                    # exact: |k| < 2^50
+    tie = sy == half_pt
+    want = k.astype(np.int64) + (sy >= half_pt)
+    ties = int(np.count_nonzero(tie))
+    if abound is not None:
+        zmax = abound + int(top) + 2
+        if zmax >= 1 << 50:
+            return f"|A ± c*X| reaches 2^{zmax.bit_length()}"
+        half_ulp = 2.0 ** (zmax.bit_length() - 1 - 53)
+        near = ~tie & (np.abs(sy - half_pt) <= 2 * half_ulp)
+        if near.any():
+            return (f"fl(A ± c*X) may round onto a half-integer "
+                    f"({int(np.count_nonzero(near))} values of X)")
+    xmax = int(np.max(np.abs(xs)))
+    r = s * Fraction(c) * Fraction(2) ** (beta - e)
+    for a, b in _convergents(r, RAT_MAX_DEN):
+        if 2 * abs(a) * xmax + b >= INT64_BUDGET:
+            break
+        y = xs * (2 * a) + b
+        h = y // (2 * b)
+        if np.array_equal(h, want) and np.array_equal(y - h * (2 * b) == 0,
+                                                      tie):
+            return (a, b), ties
+    return (f"no rational a/b with b <= 2^{RAT_MAX_DEN.bit_length() - 1} "
+            f"reproduces rint(fl({c!r} * X)) over {xs.size} values")
+
+
+def int_eval(e: Expr, leaf, beta_of):
+    """``(k, exponent)``: the value of polynomial `e` is ``k * 2^-exponent``
+    with integer ``k``.  ``leaf(ref)`` gives a `Ref`'s scaled integers
+    (numpy or jnp arrays), ``beta_of(stage)`` its grid; constants are
+    dyadic (`_poly_walk` proved the tree exact and within its carrier).
+    Sums align on the finer grid by left shifts, so no bit is lost."""
+    def go(n: Expr):
+        if isinstance(n, Const):
+            if n.value == 0:
+                return 0, 0
+            return dyadic_scale(float(n.value), max_num=F64_EXACT - 1,
+                                max_exp=60)
+        if isinstance(n, Ref):
+            return leaf(n), beta_of(n.stage)
+        if isinstance(n, Pow):
+            v, ev = go(n.base)
+            out = v
+            for _ in range(n.n - 1):
+                out = out * v
+            return out, ev * n.n
+        (lv, el), (rv, er) = go(n.left), go(n.right)
+        if n.op == "*":
+            return lv * rv, el + er
+        ex = max(el, er)
+        if ex > el:
+            lv = lv << (ex - el)
+        if ex > er:
+            rv = rv << (ex - er)
+        return (lv + rv if n.op == "+" else lv - rv), ex
+
+    return go(e)
+
+
+class _NotPoly(Exception):
+    pass
+
+
+def _poly_walk(e: Expr, in_types: Dict[str, Optional[FixedPointType]],
+               float_stored: set) -> Tuple[int, int, int]:
+    """(bound, exponent, max node bound) of an exact polynomial: every
+    node's value is k * 2^-exponent with |k| <= bound < 2^53, so the
+    oracle's f64 op at that node is exact.  Raises `_NotPoly(reason)`."""
+    from repro.core.graph import Call, Cmp, ParamRef, Select
+    peak = 0
+
+    def chk(b: int, ex: int) -> Tuple[int, int]:
+        nonlocal peak
+        if b >= F64_EXACT:
+            raise _NotPoly(f"a node needs {b.bit_length()} magnitude bits")
+        peak = max(peak, b)
+        return b, ex
+
+    def go(n: Expr) -> Tuple[int, int]:
+        if isinstance(n, Const):
+            if n.value == 0:
+                return 0, 0
+            ds = dyadic_scale(float(n.value), max_num=F64_EXACT - 1,
+                              max_exp=60)
+            if ds is None:
+                raise _NotPoly(f"constant {n.value!r} is not dyadic")
+            return chk(abs(ds[0]), ds[1])
+        if isinstance(n, Ref):
+            t = in_types.get(n.stage)
+            if t is None or n.stage in float_stored:
+                raise _NotPoly(f"input {n.stage!r} has no integer grid")
+            return chk(_qabs(t), t.beta)
+        if isinstance(n, ParamRef):
+            raise _NotPoly(f"runtime parameter {n.name!r}")
+        if isinstance(n, BinOp):
+            if n.op == "/":
+                raise _NotPoly("division rounds")
+            (bl, el), (br, er) = go(n.left), go(n.right)
+            if n.op == "*":
+                return chk(bl * br, el + er)
+            ex = max(el, er)
+            return chk((bl << (ex - el)) + (br << (ex - er)), ex)
+        if isinstance(n, Pow):
+            if n.n < 1:
+                raise _NotPoly("non-positive power")
+            b, ex = go(n.base)
+            return chk(b ** n.n, ex * n.n)
+        if isinstance(n, (Call, Cmp, Select)):
+            raise _NotPoly(f"{type(n).__name__} is not a polynomial")
+        raise _NotPoly(f"unsupported node {type(n).__name__}")
+
+    b, ex = go(e)
+    return b, ex, peak
+
+
+def _const_term(n: Expr) -> Optional[Tuple[float, Expr]]:
+    """``c * X`` or ``X * c`` with a constant c -> (c, X)."""
+    if isinstance(n, BinOp) and n.op == "*":
+        if isinstance(n.left, Const):
+            return float(n.left.value), n.right
+        if isinstance(n.right, Const):
+            return float(n.right.value), n.left
+    return None
+
+
+def _split_rational(e: Expr):
+    """The root pattern with one non-dyadic constant, ``A ± c*X`` or
+    ``c*X`` -> (A or None, c, sign of c*X, X)."""
+    ct = _const_term(e)
+    if ct is not None:
+        return None, ct[0], 1, ct[1]
+    if isinstance(e, BinOp) and e.op in ("+", "-"):
+        ct = _const_term(e.right)
+        if ct is not None:
+            return e.left, ct[0], 1 if e.op == "+" else -1, ct[1]
+    return None
+
+
+def _plan_intpoly(st: Stage, t_out: Optional[FixedPointType],
+                  in_types: Dict[str, Optional[FixedPointType]],
+                  float_stored: set, phase: Optional[PhaseSnap]):
+    """Integer evaluation of a polynomial stage, or the reason for none."""
+    if t_out is None or t_out.width > 52:
+        return "output has no integer grid"
+    if phase is not None and not phase.int_ok:
+        return "phase-split residues re-snap per lattice residue"
+    beta = t_out.beta
+    try:
+        b, ex, peak = _poly_walk(st.expr, in_types, float_stored)
+    except _NotPoly as whole:
+        parts = _split_rational(st.expr)
+        if parts is None:
+            return str(whole)
+        return _plan_rational_poly(st, t_out, in_types, float_stored, parts)
+    t = ex - beta
+    fin = (b << -t) if t <= 0 else b + (1 << (t - 1))
+    peak = max(peak, fin)
+    plan = dict(poly_exact=st.expr, carrier=_poly_carrier(peak),
+                acc_bound=b)
+    plan["proof"] = (f"intpoly {plan['carrier']}: exact polynomial, "
+                     f"nodes below 2^{peak.bit_length()}, "
+                     f"round-half-even shift {t}")
+    return plan
+
+
+def _poly_carrier(peak: int) -> str:
+    return "int32" if peak < INT32_BUDGET else "int64"
+
+
+def _plan_rational_poly(st, t_out, in_types, float_stored, parts):
+    from repro.core.graph import expr_refs
+    a_expr, c, s, x_expr = parts
+    beta = t_out.beta
+    try:
+        xb, xe, peak = _poly_walk(x_expr, in_types, float_stored)
+        ab = 0
+        if a_expr is not None:
+            ab, ae, apeak = _poly_walk(a_expr, in_types, float_stored)
+            if ae > beta:
+                return "the exact part is finer than the output grid"
+            ab <<= beta - ae
+            peak = max(peak, apeak, ab)
+    except _NotPoly as exc:
+        return str(exc)
+    refs = set(expr_refs(x_expr))
+    if len(refs) != 1:
+        return "the non-dyadic term is not a function of one input value"
+    ref = refs.pop()
+    t_in = in_types[ref.stage]
+    if t_in.int_max - t_in.int_min + 1 > ENUM_CAP:
+        return (f"input {ref.stage!r} has more than "
+                f"2^{ENUM_CAP.bit_length() - 1} grid values")
+    rat = _prove_rational(c, s, beta, xe, t_in.int_min, t_in.int_max,
+                          None if a_expr is None else ab,
+                          (x_expr, t_in.beta))
+    if isinstance(rat, str):
+        return rat
+    (a, b), ties = rat
+    y = 2 * abs(a) * xb + b
+    peak = max(peak, y, ab + y // (2 * b) + 1)
+    carrier = _poly_carrier(peak)
+    what = "A ± fl(c*X)" if a_expr is not None else "fl(c*X)"
+    return dict(poly_exact=a_expr, rat_term=x_expr,
+                rat=(a, b), carrier=carrier, acc_bound=xb, proof=(
+                    f"intpoly {carrier}, rational finish {a}/{b}: "
+                    f"rint({what}) with c={c!r} reproduced for every "
+                    f"{ref.stage} in [{t_in.int_min}, {t_in.int_max}] "
+                    f"({ties} ties to even), nodes below "
+                    f"2^{peak.bit_length()}"))
+
+
 def _phase_snap(t_union: FixedPointType, entry) -> PhaseSnap:
     (my, mx), tmap = entry
     return PhaseSnap(lattice=(my, mx), types=dict(tmap),
@@ -515,7 +878,6 @@ def lower(pipeline: Pipeline, types, params: Optional[Dict[str, float]] = None,
     retention — is recorded on the stages and, when `types` is a
     `BitwidthPlan`, appended to the plan column's provenance notes.
     """
-    from repro import obs
     if datapath not in ("exact", "narrow"):
         raise LoweringError(f"unknown datapath mode {datapath!r}; "
                             "expected 'exact' or 'narrow'")
@@ -568,45 +930,73 @@ def lower(pipeline: Pipeline, types, params: Optional[Dict[str, float]] = None,
                                             stage=st, t=t_out, halo=halo,
                                             phase=phase, **plan_int)
             else:
-                expr_dtype, election = "f64", ""
-                if narrow:
-                    reason = _expr_fits_f32(st, t_out, tmap, float_stored,
-                                            phase)
-                    if reason is None:
-                        expr_dtype, election = "f32", "f32"
-                    else:
-                        election = f"f64 kept: {reason}"
-                stages[name] = LoweredStage(name=name, kind="expr", stage=st,
-                                            t=t_out, halo=halo, phase=phase,
-                                            store_float=sf,
-                                            expr_dtype=expr_dtype,
-                                            election=election)
+                # narrow mode tries the f32 demotion first; otherwise an
+                # integer polynomial, else the f64 replay
+                f32_no = (_expr_fits_f32(st, t_out, tmap, float_stored, phase)
+                          if narrow else "")
+                if f32_no is None:
+                    stages[name] = LoweredStage(
+                        name=name, kind="expr", stage=st, t=t_out, halo=halo,
+                        phase=phase, store_float=sf, expr_dtype="f32",
+                        election="f32")
+                    continue
+                poly = ("float-stored output" if sf else
+                        _plan_intpoly(st, t_out, tmap, float_stored, phase))
+                if isinstance(poly, dict):
+                    election = ""
+                    if narrow:
+                        election = ("int32" if poly["carrier"] == "int32"
+                                    else f"int64 kept: {f32_no}; "
+                                    f"{poly['proof']}")
+                    stages[name] = LoweredStage(name=name, kind="intpoly",
+                                                stage=st, t=t_out, halo=halo,
+                                                phase=phase, election=election,
+                                                **poly)
+                    continue
+                stages[name] = LoweredStage(
+                    name=name, kind="expr", stage=st, t=t_out, halo=halo,
+                    phase=phase, store_float=sf, proof=f"f64 kept: {poly}",
+                    election=f"f64 kept: {f32_no}" if narrow else "")
         kinds = [s.kind for s in stages.values()]
-        sp.set(intlinear=kinds.count("intlinear"), expr=kinds.count("expr"))
+        sp.set(intlinear=kinds.count("intlinear"),
+               intpoly=kinds.count("intpoly"), expr=kinds.count("expr"),
+               **_census(stages.values()))
+        DATAPATH_STATS.add("lowerings")
+        for ls in stages.values():
+            if not ls.stage.is_input:
+                DATAPATH_STATS.add(f"{ls.kind}.{ls.datapath_carrier}."
+                                   f"{ls.finish}")
         if narrow:
             sp.set(narrowed=sum(1 for s in stages.values()
                                 if s.election in ("int32", "f32")
                                 or s.carrier == "int32pair"))
-            if plan_obj is not None and hasattr(plan_obj, "record_election"):
-                plan_obj.record_election(col, _election_notes(pipeline.name,
-                                                              stages))
+        if plan_obj is not None and hasattr(plan_obj, "record_election"):
+            plan_obj.record_election(col, _election_notes(
+                pipeline.name, stages, datapath))
     return LoweredPipeline(pipeline=pipeline, stages=stages, order=order,
                            params=dict(params or {}), types=tmap, column=col,
                            datapath=datapath)
 
 
-def _election_notes(pipe_name: str,
-                    stages: Dict[str, LoweredStage]) -> List[str]:
-    """Provenance lines for a narrow-mode lowering: one census line plus
-    one justification line per retained 64-bit datapath."""
+def _election_notes(pipe_name: str, stages: Dict[str, LoweredStage],
+                    datapath: str = "narrow") -> List[str]:
+    """Provenance lines of a lowering: the proof (or f64 retention
+    reason) of every exact integer finish and intpoly election, and in
+    narrow mode one census line plus one justification line per retained
+    64-bit datapath."""
     labels = []
     details = []
     for name, ls in stages.items():
         if ls.stage.is_input:
             continue
-        label = ls.carrier if ls.kind == "intlinear" else ls.expr_dtype
-        labels.append(f"{name}={label}")
-        if ls.election.startswith(("int64 kept", "f64 kept")):
+        labels.append(f"{name}={ls.datapath_carrier}")
+        if datapath == "narrow" \
+                and ls.election.startswith(("int64 kept", "f64 kept")):
             details.append(f"datapath[narrow] {pipe_name}.{name}: "
                            f"{ls.election}")
-    return [f"datapath[narrow] {pipe_name}: " + ", ".join(labels)] + details
+        elif ls.proof:
+            details.append(f"datapath[{datapath}] {pipe_name}.{name}: "
+                           f"{ls.proof}")
+    census = ([f"datapath[narrow] {pipe_name}: " + ", ".join(labels)]
+              if datapath == "narrow" else [])
+    return census + details

@@ -57,11 +57,7 @@ class Island:
         """Compact datapath census for telemetry, e.g. 'int32x3,f64x1'."""
         counts: Dict[str, int] = {}
         for n in self.stages:
-            ls = lp.stages[n]
-            if ls.kind == "intlinear":
-                label = ls.carrier
-            else:
-                label = getattr(ls, "expr_dtype", "f64")
+            label = lp.stages[n].datapath_carrier
             counts[label] = counts.get(label, 0) + 1
         return ",".join(f"{k}x{v}" for k, v in sorted(counts.items()))
 

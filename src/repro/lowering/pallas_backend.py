@@ -20,8 +20,12 @@ Per-stage datapaths are synthesized from each `LoweredStage`:
   * `intlinear` — integer multiply-accumulate over static tap slices
     (int32, an int32 *pair* with one widening combine, or int64 —
     narrow-mode election, see `repro.lowering.ir`), finished by a
-    round-half-even shift (dyadic scale) or one f64 multiply + rint,
-    saturated per lattice residue where the plan carries phase types;
+    round-half-even shift (dyadic scale), a proved integer rational
+    finish (non-dyadic scale) or, failing its proof, one f64 multiply +
+    rint, saturated per lattice residue where the plan carries phase
+    types;
+  * `intpoly`   — a polynomial of stored integer taps in its carrier,
+    finished like `intlinear` (`backends.eval_intpoly`);
   * `expr`      — the oracle's expression tree replayed on dequantized
     taps (`dsl.exec.eval_expr`) in f64, or in f32 under a narrow-mode
     exactness proof, then snapped.
@@ -70,18 +74,9 @@ def stages_needing_64bit(lp: LoweredPipeline) -> list:
     float-stored or wider-than-31-bit tiles, phase-split residue grids
     (built in int64), int64 and int32-pair carriers (the pair combines in
     int64), f64 finishes and f64 expression replays."""
-    out = []
-    for n, ls in lp.stages.items():
-        if ls.store_float \
-                or (ls.t is not None and ls.t.width > 31) \
-                or ls.phase is not None \
-                or (ls.kind == "intlinear"
-                    and (ls.carrier in ("int64", "int32pair")
-                         or not ls.dyadic)) \
-                or (ls.kind == "expr" and not ls.stage.is_input
-                    and ls.expr_dtype == "f64"):
-            out.append(n)
-    return out
+    return [n for n, ls in lp.stages.items()
+            if ls.uses_f64 or ls.wide or ls.phase is not None
+            or (ls.t is not None and ls.t.width > 31)]
 
 
 def needs_64bit(lp: LoweredPipeline) -> bool:
@@ -140,6 +135,10 @@ def _compute_descriptor(lp: LoweredPipeline, name: str, ss):
                 lambda tp: tap(tp.stage, tp.dy, tp.dx).astype(cdt),
                 lambda: jnp.zeros((rows.shape[0], cols.shape[1]), cdt))
             return B.finish_intlinear(ls, acc, rows, cols)
+    elif ls.kind == "intpoly":
+        def fn(tap, rows, cols, ls=ls):
+            return B.eval_intpoly(ls, tap, lambda i: lp.stages[i].t.beta,
+                                  rows, cols)
     else:
         deq = B.dequant_f32 if ls.expr_dtype == "f32" else B.dequant
 
@@ -227,6 +226,7 @@ def compile_pallas(lp: LoweredPipeline,
     # 64-bit datapaths exist only off-TPU (interpret mode); a 32-bit plan
     # traces without x64 so no 64-bit constant reaches the kernel
     x64 = needs_64bit(lp)
+    census = lp.census(order)
     cache: Dict[tuple, list] = {}
 
     def compile_island(isl: Island, batch: Optional[int]):
@@ -265,7 +265,8 @@ def compile_pallas(lp: LoweredPipeline,
         imgs, _ = B.normalize_images(lp, image)
         img_of = dict(zip(lp.pipeline.input_stages(), imgs))
         with obs.span("exec.pallas", backend="pallas",
-                      pipeline=lp.pipeline.name, outputs=len(outs)) as sp:
+                      pipeline=lp.pipeline.name, outputs=len(outs),
+                      **census) as sp:
 
             def to_device():
                 buffers, _ = B.ingest_host(lp, input_names, img_of)

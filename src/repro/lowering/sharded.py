@@ -164,6 +164,7 @@ def compile_sharded(lp: LoweredPipeline,
     input_names = [n for n in order if lp.stages[n].stage.is_input]
     cache: Dict[tuple, tuple] = {}
     x64 = needs_64bit(lp)
+    census = lp.census(order)
 
     def build(shape, mesh):
         batch = shape[0] if len(shape) == 3 else None
@@ -189,7 +190,7 @@ def compile_sharded(lp: LoweredPipeline,
         img_of = dict(zip(lp.pipeline.input_stages(), imgs))
         with obs.span("exec.sharded", backend="sharded",
                       pipeline=lp.pipeline.name, outputs=len(outs),
-                      shards=m.shape["band"]) as sp:
+                      shards=m.shape["band"], **census) as sp:
 
             def to_device():
                 # narrow replicated inputs: container-dtype frames ship

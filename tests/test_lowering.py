@@ -211,12 +211,18 @@ def test_lowering_kind_selection():
     pipe = hcd.build()
     lp = lower(pipe, _types_for(pipe))
     kinds = lp.kinds()
-    # box sums are dyadic-integer stencils; Sobel/12 is intlinear with an
-    # f64 finishing multiply; det/harris are expr replays
+    # box sums are dyadic-integer stencils; Sobel/12 is intlinear with a
+    # proved integer rational finish; the products, det and harris are
+    # integer polynomials (harris with the rational finish of 0.04*T)
     assert kinds["Sxx"] == "intlinear" and lp.stages["Sxx"].dyadic
     assert kinds["Ix"] == "intlinear" and not lp.stages["Ix"].dyadic
-    assert kinds["det"] == "expr"
-    assert kinds["harris"] == "expr"
+    assert lp.stages["Ix"].finish == "rational"
+    assert lp.stages["Ix"].rat == (1, 12)
+    assert kinds["Ixx"] == kinds["det"] == kinds["harris"] == "intpoly"
+    assert lp.stages["det"].finish == "shift"
+    assert lp.stages["harris"].finish == "rational"
+    assert lp.stages["harris"].carrier == "int64"
+    assert "expr" not in kinds.values()
 
 
 def test_negative_shift_elects_wide_carrier():

@@ -1,7 +1,8 @@
 """Compile the main path's device programs for a TPU v5e that is
 described, not attached: the fused band kernel (dus_ext at 1080p and
-4K), the lowered XLA forward of usm and hcd at 1080p, and the 4-device
-band-sharded program at 4K.  What the TPU compilers refuse fails here,
+4K, and a 32-bit plan with hcd's integer finishes), the lowered XLA
+forward of usm and hcd at 1080p, and the 4-device band-sharded program
+at 4K.  What the TPU compilers refuse fails here,
 at no chip time.
 
 The topology is described inside a module fixture, never at import: only
@@ -84,8 +85,9 @@ def test_fused_kernel_compiles_for_v5e(one_chip, shape):
 
 @pytest.mark.parametrize("name", ["usm", "hcd"])
 def test_lowered_forward_compiles_for_v5e(one_chip, name):
-    """XLA:TPU takes the exact datapaths' f64/int64 ops (compiling; hcd's
-    f64 `harris` replay is not bit-exact on the chip — see ROADMAP S3)."""
+    """XLA:TPU takes the lowered programs: usm's f64 replays, and hcd's
+    all-integer program (int32, and int64 for `det`/`harris`, with no
+    f64 op: the chip computes the oracle's integers exactly)."""
     import jax
     pipe, params = {"usm": (usm.build(), dict(usm.DEFAULT_PARAMS)),
                     "hcd": (hcd.build(), {})}[name]
@@ -95,6 +97,34 @@ def test_lowered_forward_compiles_for_v5e(one_chip, name):
         fwd = jax.jit(jax.vmap(run.forward))
         fwd.lower(*_frame_args(run.lowered, pipe.input_stages(),
                                (BATCH, 1080, 1920), one_chip)).compile()
+
+
+def test_integer_finishes_compile_in_the_kernel_for_v5e(one_chip):
+    """A 32-bit plan with hcd's Sobel/12 rational finish and an integer
+    product (``intpoly``) reaches the fused kernel: Mosaic takes the
+    int32 division and tie select of the rational finish."""
+    from repro.dsl.builder import PipelineBuilder
+    from repro.kernels.stencil.kernel import fused_pipeline
+    from repro.lowering.pallas_backend import island_program, needs_64bit
+    p = PipelineBuilder("hcd_front")
+    img = p.image("img", 0, 255)
+    ix = p.stencil("Ix", img, hcd.SOBEL_X, scale=1.0 / 12)
+    iy = p.stencil("Iy", img, hcd.SOBEL_Y, scale=1.0 / 12)
+    p.output(p.define("Ixy", ix * iy))
+    pipe = p.build()
+    lp = lower(pipe, _types(pipe))
+    assert lp.stages["Ix"].finish == "rational"
+    assert lp.stages["Ixy"].kind == "intpoly"
+    assert not needs_64bit(lp)
+    plan = partition_islands(lp, (1080, 1920))
+    for isl in plan.islands:
+        call = fused_pipeline(island_program(lp, isl),
+                              grid=isl.schedule.grid,
+                              name=f"fused_band_island_{isl.idx}",
+                              interpret=False, batch=BATCH)
+        compiled = call.lower(*_frame_args(
+            lp, isl.inputs, (BATCH, 1080, 1920), one_chip)).compile()
+        assert "tpu_custom_call" in compiled.as_text()
 
 
 def test_sharded_band_walk_compiles_for_4_devices(topo):
