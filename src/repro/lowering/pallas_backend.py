@@ -228,6 +228,7 @@ def compile_pallas(lp: LoweredPipeline,
     x64 = needs_64bit(lp)
     census = lp.census(order)
     cache: Dict[tuple, list] = {}
+    host_buffers = B.HostBuffers()
 
     def compile_island(isl: Island, batch: Optional[int]):
         return fused_pipeline(island_program(lp, isl),
@@ -292,7 +293,8 @@ def compile_pallas(lp: LoweredPipeline,
                 return buffers
 
             with jax.enable_x64(x64):
-                res = B.run_on_device(lp, outs, to_device, dispatch)
+                res = B.run_on_device(lp, outs, to_device, dispatch,
+                                      host_buffers)
         # fused kernels: intermediates never leave their island's bands,
         # so telemetry covers the materialized boundaries + outputs only
         obs.runtime.record_env(res, lp, backend="pallas")

@@ -163,6 +163,7 @@ def compile_sharded(lp: LoweredPipeline,
     order = B.needed_stages(lp, outs)
     input_names = [n for n in order if lp.stages[n].stage.is_input]
     cache: Dict[tuple, tuple] = {}
+    host_buffers = B.HostBuffers()
     x64 = needs_64bit(lp)
     census = lp.census(order)
 
@@ -219,7 +220,8 @@ def compile_sharded(lp: LoweredPipeline,
                 return buffers
 
             with jax.enable_x64(x64):
-                res = B.run_on_device(lp, outs, to_device, dispatch)
+                res = B.run_on_device(lp, outs, to_device, dispatch,
+                                      host_buffers)
         # like pallas: intermediates never materialize, telemetry covers
         # island boundaries + outputs only
         obs.runtime.record_env(res, lp, backend="sharded")

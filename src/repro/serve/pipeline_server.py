@@ -77,6 +77,13 @@ class PipelineServer:
     ``batch_timeout_s`` bounds how long the batcher holds a partial
     batch open waiting for more requests (the classic throughput vs
     tail-latency knob); 0 serves whatever is immediately queued.
+
+    A result's arrays are views of its batch's f64 buffers, which the
+    executor recycles once no result of the batch (nor any view of one)
+    is alive; a held result is never overwritten.  Holding one makes the
+    executor widen a later batch into fresh buffers, which costs a page
+    fault per page (about 16 ms a 1080p map a frame on a TPU v5e host):
+    copy what you keep and drop the result.
     """
 
     def __init__(self, pipeline, types, params: Optional[dict] = None,

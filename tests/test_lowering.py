@@ -667,19 +667,25 @@ from types import SimpleNamespace
                          ids=lambda d: np.dtype(d).name)
 def test_dequant_host_widens_every_container_bit_exact(container):
     """One ufunc pass equals ``astype(float64) * 2**-beta`` bit for bit
-    at the container's extremes and zero, into a fresh float64 array."""
+    at the container's extremes and zero, into a new float64 array, and
+    into a given one (`out=`, a recycled buffer) that it overwrites
+    whole."""
     info = np.iinfo(container)
     a = np.array([[info.min, 0, info.max], [info.max, info.min, 0]],
                  dtype=container)
     for beta in (0, 4, 13):
         ls = SimpleNamespace(store_float=False,
                              t=FixedPointType(alpha=8, beta=beta))
-        got = B.dequant_host(ls, a)
         want = a.astype(np.float64) * 2.0 ** -beta
-        assert got.dtype == np.float64 and got.shape == a.shape
-        np.testing.assert_array_equal(got.view(np.int64),
-                                      want.view(np.int64), err_msg=beta)
-        assert not np.shares_memory(got, a)
+        stale = np.full(a.shape, np.nan)
+        got = B.dequant_host(ls, a)
+        into = B.dequant_host(ls, a, out=stale)
+        assert into is stale
+        for g in (got, into):
+            assert g.dtype == np.float64 and g.shape == a.shape
+            np.testing.assert_array_equal(g.view(np.int64),
+                                          want.view(np.int64), err_msg=beta)
+            assert not np.shares_memory(g, a)
 
 
 def test_dequant_host_passes_float_stored_tiles_through():
@@ -736,3 +742,201 @@ def test_lowered_run_widens_containers_to_oracle_f64(name, build, params,
         assert env[stage].dtype == np.float64, stage
         np.testing.assert_array_equal(np.asarray(oracle[stage]), env[stage],
                                       err_msg=f"{name}/{stage}")
+
+
+# ---------------------------------------------------------------------------
+# recycled host buffers (`HostBuffers`): a result is never overwritten
+# while any reference to it, or to a view of it, is alive
+# ---------------------------------------------------------------------------
+
+import threading  # noqa: E402
+
+from repro import obs  # noqa: E402
+
+
+def _pooled_usm(n_batches):
+    """A fresh `jnp` executor of usm (so a pool of its own), distinct
+    (3, 24, 32) batches and their oracle outputs."""
+    pipe = usm.build()
+    types = _types_for(pipe)
+    params = dict(usm.DEFAULT_PARAMS)
+    run = compile_backend(lower(pipe, types, params=params), "jnp")
+    imgs = [np.stack([_img((24, 32), seed=900 + 3 * i + b)
+                      for b in range(3)]) for i in range(n_batches)]
+    oracle = [run_fixed(pipe, im, types, params) for im in imgs]
+    return run, imgs, oracle
+
+
+def _ptr(a):
+    return a.__array_interface__["data"][0]
+
+
+def test_released_host_buffer_is_reused():
+    run, imgs, oracle = _pooled_usm(2)
+    first = run(imgs[0])
+    ptrs = {n: _ptr(a) for n, a in first.items()}
+    del first
+    again = run(imgs[1])
+    assert {n: _ptr(a) for n, a in again.items()} == ptrs
+    for n, a in again.items():
+        np.testing.assert_array_equal(np.asarray(oracle[1][n]), a)
+
+
+HOLDS = {
+    "whole": lambda a: a,
+    "frame": lambda a: a[1],
+    "slice_of_frame": lambda a: a[1][2:7, 3:],
+    "memoryview": lambda a: memoryview(a[1]),
+}
+
+
+@pytest.mark.parametrize("hold", sorted(HOLDS))
+def test_held_host_buffer_is_never_reused(hold):
+    """However the caller holds a result, no later call widens into its
+    buffer, and its values stay as they were; the calls between still
+    recycle the buffers they drop."""
+    run, imgs, oracle = _pooled_usm(11)
+    res = run(imgs[0])
+    held = {n: HOLDS[hold](a) for n, a in res.items()}
+    ptrs = {n: _ptr(a) for n, a in res.items()}
+    want = {n: np.array(h, copy=True) for n, h in held.items()}
+    del res
+    B.HOST_BUFFER_STATS.reset()
+    for im in imgs[1:]:
+        out = run(im)
+        for n, a in out.items():
+            assert _ptr(a) != ptrs[n], n
+            assert not np.shares_memory(a, np.asarray(held[n])), n
+        del out
+    assert B.HOST_BUFFER_STATS["reused"] > 0
+    for n, h in held.items():
+        np.testing.assert_array_equal(np.asarray(h).view(np.int64),
+                                      want[n].view(np.int64), err_msg=n)
+        np.testing.assert_array_equal(
+            np.asarray(h), np.asarray(HOLDS[hold](np.asarray(oracle[0][n]))),
+            err_msg=n)
+
+
+def test_concurrent_callers_never_share_a_host_buffer():
+    """Two threads on one executor: each holds its last result through
+    its next call, and neither sees it change; buffers are recycled
+    meanwhile."""
+    run, imgs, oracle = _pooled_usm(16)
+    run(imgs[0])                          # compile before the race
+    B.HOST_BUFFER_STATS.reset()
+    errors = []
+    start = threading.Barrier(2)
+
+    def worker(t):
+        start.wait()
+        prev = None
+        for i in range(t, len(imgs), 2):
+            out = run(imgs[i])
+            for j, res in [(i, out)] + ([prev] if prev else []):
+                for n, a in res.items():
+                    if not np.array_equal(a, np.asarray(oracle[j][n])):
+                        errors.append((t, j, n))
+            prev = (i, out)
+
+    threads = [threading.Thread(target=worker, args=(t,)) for t in range(2)]
+    for th in threads:
+        th.start()
+    for th in threads:
+        th.join()
+    assert not errors
+    assert B.HOST_BUFFER_STATS["reused"] > 0
+
+
+def test_host_buffers_hand_each_buffer_to_one_thread_at_a_time():
+    """More threads than cores share one pool with a short switch
+    interval; each writes its own mark into the buffer it holds and
+    finds it unchanged, so no buffer is ever held by two at once."""
+    import os
+    import sys
+    import time
+    pool = B.HostBuffers()
+    n = min((os.cpu_count() or 4) + 1, 65)
+    start = threading.Barrier(n)
+    errors, hits = [], []
+
+    def worker(t):
+        start.wait()
+        for _ in range(300):
+            buf, hit = pool.acquire("y", (64,))
+            buf[:] = t
+            time.sleep(0)
+            if not (buf == t).all():
+                errors.append(t)
+            hits.append(hit)
+            del buf
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=worker, args=(t,))
+                   for t in range(n)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=120)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(th.is_alive() for th in threads)
+    assert not errors and len(hits) == 300 * n
+    assert any(hits)
+    assert 1 <= len(pool._slots[("y", (64,))]) <= B._SLOT_BUFFERS
+
+
+def _one_output_pipeline():
+    """`run_on_device`'s arguments for a stand-in program with one
+    int-stored output ``y`` and one float-stored output ``f``."""
+    import jax.numpy as jnp
+    lp = SimpleNamespace(stages={
+        "y": SimpleNamespace(store_float=False,
+                             t=FixedPointType(alpha=4, beta=1)),
+        "f": SimpleNamespace(store_float=True, t=None)})
+    x = jnp.arange(6, dtype=jnp.int32).reshape(2, 3)
+
+    def call(pool, k=0):
+        return B.run_on_device(lp, ["y", "f"], lambda: x,
+                               lambda a: {"y": a + k,
+                                          "f": a.astype(jnp.float32)},
+                               pool)
+    return call
+
+
+def test_host_buffer_pool_stays_bounded_when_every_result_is_kept():
+    call = _one_output_pipeline()
+    pool = B.HostBuffers()
+    B.HOST_BUFFER_STATS.reset()
+    n = B._SLOT_BUFFERS + 3
+    kept = [call(pool, k) for k in range(n)]
+    assert [len(s) for s in pool._slots.values()] == [B._SLOT_BUFFERS]
+    assert dict(B.HOST_BUFFER_STATS) == {"reused": 0, "fresh": n}
+    assert len({_ptr(r["y"]) for r in kept}) == n
+    for k, r in enumerate(kept):
+        np.testing.assert_array_equal(r["y"], (np.arange(6) + k).reshape(
+            2, 3) / 2)
+    del kept, r
+    call(pool)                   # every buffer released: one is reused
+    assert B.HOST_BUFFER_STATS["reused"] == 1
+    assert [len(s) for s in pool._slots.values()] == [B._SLOT_BUFFERS]
+
+
+def test_dequant_span_and_counters_count_reused_and_fresh():
+    call = _one_output_pipeline()
+    pool = B.HostBuffers()
+    B.HOST_BUFFER_STATS.reset()
+    with obs.tracing() as tr:
+        a = call(pool)           # empty pool: fresh
+        b = call(pool)           # a is held: fresh
+        del a
+        c = call(pool)           # a's buffer is free again
+        del b, c
+        call(pool)
+    got = [(s.attrs["reused"], s.attrs["fresh"])
+           for s in tr.spans("exec.dequant")]
+    assert got == [(0, 1), (0, 1), (1, 0), (1, 0)]
+    assert dict(B.HOST_BUFFER_STATS) == {"reused": 2, "fresh": 2}
+    call(pool)                   # untraced: counted all the same
+    assert dict(B.HOST_BUFFER_STATS) == {"reused": 3, "fresh": 2}

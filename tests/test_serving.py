@@ -12,6 +12,7 @@ Three contracts under test (docs/serving.md):
   * **PipelineServer** — fixed-batch padding, drain-on-close, and
     end-to-end oracle equality through the background batcher.
 """
+import collections
 import contextlib
 import threading
 import warnings
@@ -466,7 +467,7 @@ def test_h2d_waits_for_the_inputs_to_land(monkeypatch, traced):
         store_float=False, t=FixedPointType(alpha=4, beta=1))})
     with (obs.tracing() if traced else contextlib.nullcontext()):
         res = B.run_on_device(lp, ["y"], lambda: args,
-                              lambda a: {"y": a["a"] + 1})
+                              lambda a: {"y": a["a"] + 1}, B.HostBuffers())
     np.testing.assert_array_equal(res["y"], np.arange(1, 5) / 2)
     assert len(waited) == 2 and waited[0][1] is args
     assert set(waited[1][1]) == {"y"}
@@ -504,3 +505,80 @@ def test_untraced_serving_computes_no_island_attributes(monkeypatch):
             np.testing.assert_array_equal(a[k], b[k])
     isl = tr.spans("exec.pallas.island")
     assert len(isl) > 1 and all(s.attrs["containers"] for s in isl)
+
+
+# ---------------------------------------------------------------------------
+# recycled output buffers: a served result is never overwritten while
+# its client holds it, and results the client drops are widened into
+# again
+# ---------------------------------------------------------------------------
+
+RECYCLE = [("lowered", usm.build, dict(usm.DEFAULT_PARAMS)),
+           ("pallas", dus.build, {})]
+
+
+def _closed_loop(srv, frames, keep):
+    """A batch client: two batches in flight, each result reaped as it
+    returns and kept only where ``keep(i)``; returns {i: kept result}."""
+    pending: collections.deque = collections.deque()
+    kept = {}
+
+    def reap():
+        i, fut = pending.popleft()
+        res = fut.result(timeout=120)
+        if keep(i):
+            kept[i] = res
+
+    for i, f in enumerate(frames):
+        pending.append((i, srv.submit(f)))
+        while len(pending) > 2 * srv.batch_size:
+            reap()
+    while pending:
+        reap()
+    return kept
+
+
+def _serve_recycling(backend, build, params, keep, n_batches=30):
+    from repro.lowering import backends as B
+    from repro.serve import PipelineServer
+    pipe = build()
+    types = _types_for(pipe)
+    shape = (32, 32)
+    frames = list(_batch(1, 2 * n_batches, shape, seed=61).astype(
+        np.uint8))
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", RuntimeWarning)
+        with PipelineServer(pipe, types, params, backend=backend,
+                            batch_size=2) as srv:
+            srv.warmup([shape])
+            B.HOST_BUFFER_STATS.reset()
+            kept = _closed_loop(srv, frames, keep)
+    return pipe, types, frames, kept, dict(B.HOST_BUFFER_STATS)
+
+
+@pytest.mark.parametrize("backend,build,params", RECYCLE,
+                         ids=[r[0] for r in RECYCLE])
+def test_served_results_are_never_overwritten(backend, build, params):
+    """Every third frame's result is kept to the end, pinning its batch's
+    buffers, while the batches with no kept frame are recycled; every
+    kept result still equals the oracle after the last batch."""
+    pipe, types, frames, kept, stats = _serve_recycling(
+        backend, build, params, keep=lambda i: i % 3 == 0)
+    assert sorted(kept) == list(range(0, len(frames), 3))
+    assert stats["reused"] > 0 and stats["fresh"] > 0
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", RuntimeWarning)
+        for i, res in kept.items():
+            ref = run_fixed(pipe, frames[i], types, params)
+            for k, v in res.items():
+                np.testing.assert_array_equal(np.asarray(ref[k]), v,
+                                              err_msg=f"frame {i}/{k}")
+
+
+@pytest.mark.parametrize("backend,build,params", RECYCLE,
+                         ids=[r[0] for r in RECYCLE])
+def test_dropped_served_results_are_recycled(backend, build, params):
+    *_, stats = _serve_recycling(backend, build, params,
+                                 keep=lambda i: False)
+    assert stats["reused"] > 0
+    assert stats["fresh"] < stats["reused"] / 4
